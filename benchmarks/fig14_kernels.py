@@ -70,7 +70,7 @@ def keyswitch_comparison(records, smoke: bool) -> None:
         for q in ctx.primes[:level + 1]])[None])
 
     fks = FusedKeySwitch(ctx)
-    km = fks.ksk_mont("relin", level, rk.data)
+    km = fks.ksk_mont("relin", rk.data)
     kcom.reset_dispatch_count()
     fks.apply(d2, level, km, interpret=True)
     fused_disp = kcom.dispatch_count()

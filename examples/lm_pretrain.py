@@ -13,7 +13,6 @@ import argparse
 import time
 
 import jax
-from repro.compat import set_mesh as compat_set_mesh
 
 from repro.data.pipeline import SyntheticLMDataset, shard_batch
 from repro.launch.mesh import make_host_mesh
@@ -48,7 +47,7 @@ def main():
 
     opt = adamw_init(params)
     ds = SyntheticLMDataset(cfg, args.batch, args.seq)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn = jax.jit(M.make_train_step(cfg, mesh, learning_rate=6e-4))
         sup = Supervisor(step_fn, args.ckpt_dir, ckpt_every=100)
         t0 = time.time()

@@ -47,6 +47,7 @@ model bills for.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -54,6 +55,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import ntt as nttm
 from repro.core import ops as hops
 from repro.core.ciphertext import Ciphertext, KeySwitchKey, Plaintext
 from repro.core.context import CkksContext
@@ -95,6 +97,17 @@ def const_vec(op: FheOp, consts: Dict[str, np.ndarray],
     v = resolve_cexpr(op_cexpr(op), consts)
     assert v.shape[-1] == slots, f"const for op {op.idx} has {v.shape} slots"
     return v
+
+
+def galois_element(op: FheOp, params: CkksParams) -> Optional[int]:
+    """The Galois element whose key ``op`` switches to, or None when it
+    needs no Galois key (not a rotation, or a rotation by 0 slots)."""
+    if op.kind == "rotate":
+        step = op.meta["step"] % params.slots
+        return nttm.galois_element(step, params.n) if step else None
+    if op.kind == "conjugate":
+        return 2 * params.n - 1
+    return None
 
 
 def _const_key(op: FheOp) -> str:
@@ -148,6 +161,14 @@ class CkksEngine:
                  use_kernels: bool = False):
         self.params = params
         self.ctx = CkksContext(params)
+        # kernel route: every NTT runs as the Pallas limb NTT over these
+        # tables (on a TPU the u64 library NTT costs over a minute of
+        # compilation per shape, the kernel a couple of seconds)
+        self.ntt_tables = None
+        if use_kernels:
+            from repro.kernels.limb_ntt import LimbNtt, context_tables
+            self.ntt_tables = context_tables(self.ctx)
+            self.ctx = self.ctx.with_transform(LimbNtt(self.ntt_tables))
         self.encoder = CkksEncoder(self.ctx)
         self.encryptor = CkksEncryptor(self.ctx, seed=seed)
         self.sk = self.encryptor.keygen()
@@ -207,12 +228,9 @@ class CkksEngine:
 
     def decode_batch(self, cb: CtBatch) -> np.ndarray:
         """One batched decrypt dispatch, then per-element host decode."""
-        from repro.core import modarith as ma
-        idx = self.ctx.q_idx(cb.level)
-        q = self.ctx.q_all[np.array(idx)][:, None]
-        s = self.sk.s_ntt[np.array(idx)]
-        m = ma.addmod(cb.data[:, 0], ma.mulmod(cb.data[:, 1], s, q), q)
-        m = np.asarray(m)                       # (B, L, N)
+        from repro.core.encryptor import decrypt_data
+        m = np.asarray(decrypt_data(cb.data, self.sk.s_ntt,
+                                    self.ctx.q_all))        # (B, L, N)
         return np.stack([self.encoder.decode(jnp.asarray(m[i]), cb.scale,
                                              cb.level)
                          for i in range(m.shape[0])])
@@ -236,26 +254,23 @@ class CkksEngine:
     # -- compiled batched op appliers ---------------------------------------
 
     def _opfn(self, key: Tuple, build: Callable) -> Callable:
-        """Memoized applier for one (kind, batch, level, ...) signature.
-
-        `build` returns the *eager* vmapped function. The first call
-        runs it un-jitted: CkksContext lazily builds NTT/BConv tables
-        on first use, and those must materialize as concrete arrays —
-        built inside a jit trace they would be cached as leaked tracers
-        (omnistaging stages every op in a trace, concrete operands or
-        not). Once warm, the jitted version is cached for every later
-        call, so steady-state serving pays one XLA launch per op.
-        """
+        """Memoized jitted applier for one (kind, batch, level, ...)
+        signature; ``build(ctx)`` returns the vmapped function over
+        ``ctx``. On the kernel route the limb-NTT tables are an argument
+        of the program, not constants baked into it."""
         fn = self._opfns.get(key)
         if fn is not None:
             return fn
-        eager = build()
-
-        def first(*args):
-            out = eager(*args)
-            self._opfns[key] = jax.jit(eager)
-            return out
-        return first
+        if self.ntt_tables is None:
+            fn = jax.jit(build(self.ctx))
+        else:
+            from repro.kernels.limb_ntt import LimbNtt
+            ctx = self.ctx
+            jitted = jax.jit(lambda tabs, *a: build(
+                ctx.with_transform(LimbNtt(tabs)))(*a))
+            fn = functools.partial(jitted, self.ntt_tables)
+        self._opfns[key] = fn
+        return fn
 
     def _mod_switch(self, cb: CtBatch, level: int) -> CtBatch:
         assert level <= cb.level
@@ -274,11 +289,11 @@ class CkksEngine:
                                level + 1, key=("unit",))
         key = ("adjust", cb.batch, cb.level, float(cb.scale), float(scale))
 
-        def build():
+        def build(ctx):
             lvl, s = cb.level, cb.scale
 
             def f(d, ptd):
-                out = hops.pmul(self.ctx, Ciphertext(d, lvl, s),
+                out = hops.pmul(ctx, Ciphertext(d, lvl, s),
                                 Plaintext(ptd, lvl, pt_scale))
                 return out.data
             return jax.vmap(f, in_axes=(0, None))
@@ -314,12 +329,12 @@ class CkksEngine:
         c0, c1 = self._aligned(c0, c1)
         key = (kind, c0.batch, c0.level)
 
-        def build():
+        def build(ctx):
             lvl, s = c0.level, c0.scale
             fn = hops.hadd if kind == "hadd" else hops.hsub
 
             def f(d0, d1):
-                return fn(self.ctx, Ciphertext(d0, lvl, s),
+                return fn(ctx, Ciphertext(d0, lvl, s),
                           Ciphertext(d1, lvl, s)).data
             return jax.vmap(f)
         return CtBatch(self._opfn(key, build)(c0.data, c1.data),
@@ -333,7 +348,7 @@ class CkksEngine:
         Galois keys ride the same per-(batch, level) compiled pipeline)."""
         if self._fks is None:
             from repro.kernels.keyswitch import FusedKeySwitch
-            self._fks = FusedKeySwitch(self.ctx)
+            self._fks = FusedKeySwitch(self.ctx, self.ntt_tables)
         return self._fks
 
     def _hmul_fused(self, c0: CtBatch, c1: CtBatch, lazy: bool) -> CtBatch:
@@ -345,11 +360,10 @@ class CkksEngine:
         c1 = self._mod_switch(c1, lvl)
         key = ("hmul_tensor", c0.batch, lvl)
 
-        def build_tensor():
-            q = self.ctx.q_all[: lvl + 1][:, None]
-
+        def build_tensor(ctx):
             def f(d0, d1):
                 from repro.core import modarith as ma
+                q = ctx.q_all[: lvl + 1][:, None]
                 b0, a0 = d0[0], d0[1]
                 b1, a1 = d1[0], d1[1]
                 t0 = ma.mulmod(b0, b1, q)
@@ -359,15 +373,14 @@ class CkksEngine:
                 return jnp.stack([t0, t1]), d2
             return jax.vmap(f)
         d01, d2 = self._opfn(key, build_tensor)(c0.data, c1.data)
-        km = self.fused_ks.ksk_mont("relin", lvl, self.rk.data)
+        km = self.fused_ks.ksk_mont("relin", self.rk.data)
         e0, e1 = self.fused_ks.apply(d2, lvl, km)
         ckey = ("hmul_combine", c0.batch, lvl)
 
-        def build_combine():
-            q = self.ctx.q_all[: lvl + 1][:, None]
-
+        def build_combine(ctx):
             def f(d, e0_, e1_):
                 from repro.core import modarith as ma
+                q = ctx.q_all[: lvl + 1][:, None]
                 return jnp.stack([ma.addmod(d[0], e0_, q),
                                   ma.addmod(d[1], e1_, q)])
             return jax.vmap(f)
@@ -381,12 +394,12 @@ class CkksEngine:
         lvl = min(c0.level, c1.level)
         key = ("hmul", c0.batch, c0.level, c1.level, lazy)
 
-        def build():
+        def build(ctx):
             l0, l1 = c0.level, c1.level
             s0, s1 = c0.scale, c1.scale
 
             def f(d0, d1, rkd):
-                out = hops.hmul(self.ctx, Ciphertext(d0, l0, s0),
+                out = hops.hmul(ctx, Ciphertext(d0, l0, s0),
                                 Ciphertext(d1, l1, s1),
                                 KeySwitchKey(rkd), do_rescale=not lazy)
                 return out.data
@@ -400,11 +413,11 @@ class CkksEngine:
     def _rescale(self, cb: CtBatch) -> CtBatch:
         key = ("rescale", cb.batch, cb.level)
 
-        def build():
+        def build(ctx):
             lvl, s = cb.level, cb.scale
 
             def f(d):
-                return hops.rescale(self.ctx,
+                return hops.rescale(ctx,
                                     Ciphertext(d, lvl, s)).data
             return jax.vmap(f)
         return CtBatch(self._opfn(key, build)(cb.data), cb.level - 1,
@@ -416,10 +429,17 @@ class CkksEngine:
         so ONE dispatch covers the whole batch."""
         from repro.kernels import ops as kops
         b, _, lp, n = cb.data.shape
-        primes = [self.ctx.primes[i] for i in range(lp)] * (2 * b)
-        a = cb.data.reshape(2 * b * lp, n)
-        w = jnp.tile(pt.data[: lp], (2 * b, 1))
-        data = kops.modmul(a, w, primes).reshape(b, 2, lp, n)
+        key = ("pmul_kernel", b, cb.level)
+
+        def build(ctx):
+            primes = [ctx.primes[i] for i in range(lp)] * (2 * b)
+
+            def f(d, ptd):
+                a = d.reshape(2 * b * lp, n)
+                w = jnp.tile(ptd[: lp], (2 * b, 1))
+                return kops.modmul(a, w, primes).reshape(b, 2, lp, n)
+            return f
+        data = self._opfn(key, build)(cb.data, pt.data)
         return CtBatch(data, cb.level, cb.scale * pt.scale)
 
     def _pmul(self, cb: CtBatch, pt: Plaintext, lazy: bool) -> CtBatch:
@@ -428,11 +448,11 @@ class CkksEngine:
             return out if lazy else self._rescale(out)
         key = ("pmul", cb.batch, cb.level, lazy)
 
-        def build():
+        def build(ctx):
             lvl, s, ps = cb.level, cb.scale, pt.scale
 
             def f(d, ptd):
-                out = hops.pmul(self.ctx, Ciphertext(d, lvl, s),
+                out = hops.pmul(ctx, Ciphertext(d, lvl, s),
                                 Plaintext(ptd, lvl, ps),
                                 do_rescale=not lazy)
                 return out.data
@@ -446,11 +466,11 @@ class CkksEngine:
     def _padd(self, cb: CtBatch, pt: Plaintext) -> CtBatch:
         key = ("padd", cb.batch, cb.level)
 
-        def build():
+        def build(ctx):
             lvl, s = cb.level, cb.scale
 
             def f(d, ptd):
-                return hops.padd(self.ctx, Ciphertext(d, lvl, s),
+                return hops.padd(ctx, Ciphertext(d, lvl, s),
                                  Plaintext(ptd, lvl, s)).data
             return jax.vmap(f, in_axes=(0, None))
         return CtBatch(self._opfn(key, build)(cb.data, pt.data),
@@ -463,25 +483,25 @@ class CkksEngine:
         gk = self._gk(elt)
         lvl = cb.level
         perm = self.ctx.eval_perm(elt)
-        key = ("galois_rot", cb.batch, lvl, elt)
+        key = ("galois_rot", cb.batch, lvl)
 
-        def build_rot():
-            def f(d):
-                return d[:, :, perm]
-            return jax.vmap(f)
-        rot = self._opfn(key, build_rot)(cb.data)       # (B, 2, L, N)
-        km = self.fused_ks.ksk_mont(("gk", elt), lvl, gk.data)
-        e0, e1 = self.fused_ks.apply(rot[:, 1], lvl, km)
+        def build_rot(ctx):
+            # the permutation is an argument: one program for every elt
+            def f(d, perm_):
+                return d[0][:, perm_], d[1][:, perm_]
+            return jax.vmap(f, in_axes=(0, None))
+        rot_b, rot_a = self._opfn(key, build_rot)(cb.data, perm)
+        km = self.fused_ks.ksk_mont(("gk", elt), gk.data)
+        e0, e1 = self.fused_ks.apply(rot_a, lvl, km)
         ckey = ("galois_combine", cb.batch, lvl)
 
-        def build_combine():
-            q = self.ctx.q_all[: lvl + 1][:, None]
-
+        def build_combine(ctx):
             def f(b_rot, e0_, e1_):
                 from repro.core import modarith as ma
+                q = ctx.q_all[: lvl + 1][:, None]
                 return jnp.stack([ma.addmod(b_rot, e0_, q), e1_])
             return jax.vmap(f)
-        data = self._opfn(ckey, build_combine)(rot[:, 0], e0, e1)
+        data = self._opfn(ckey, build_combine)(rot_b, e0, e1)
         return CtBatch(data, lvl, cb.scale)
 
     def _galois(self, cb: CtBatch, elt: int) -> CtBatch:
@@ -490,11 +510,11 @@ class CkksEngine:
         gk = self._gk(elt)
         key = ("galois", cb.batch, cb.level, elt)
 
-        def build():
+        def build(ctx):
             lvl, s = cb.level, cb.scale
 
             def f(d, gkd):
-                return hops._apply_galois(self.ctx, Ciphertext(d, lvl, s),
+                return hops._apply_galois(ctx, Ciphertext(d, lvl, s),
                                           elt, KeySwitchKey(gkd)).data
             return jax.vmap(f, in_axes=(0, None))
         return CtBatch(self._opfn(key, build)(cb.data, gk.data),
@@ -531,15 +551,9 @@ class CkksEngine:
                 pt = self.encode_const(v, a[0].scale, a[0].level,
                                        key=const_scope + (_const_key(op),))
                 out = self._padd(a[0], pt)
-            elif op.kind == "rotate":
-                step = op.meta["step"] % slots
-                if step == 0:
-                    out = a[0]
-                else:
-                    out = self._galois(a[0],
-                                       self.ctx.rotation_element(step))
-            elif op.kind == "conjugate":
-                out = self._galois(a[0], self.ctx.conj_element)
+            elif op.kind in ("rotate", "conjugate"):
+                elt = galois_element(op, self.params)
+                out = a[0] if elt is None else self._galois(a[0], elt)
             elif op.kind == "rescale":
                 out = self._rescale(a[0])
             elif op.kind == "bootstrap":

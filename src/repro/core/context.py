@@ -6,10 +6,12 @@ Everything host-precomputed once; runtime ops are pure jnp on these arrays.
 """
 from __future__ import annotations
 
+import copy
 import functools
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core import modarith as ma
@@ -19,9 +21,18 @@ from repro.core.params import CkksParams
 
 
 class CkksContext:
+    """``transform``, when given, runs every NTT/iNTT in place of the
+    library transform: an object with ``ntt(a, idx)`` and ``intt(a,
+    idx)`` over global limb indices, bit-identical to core/ntt.py (the
+    engine's kernel route hands in the Pallas limb NTT).
 
-    def __init__(self, params: CkksParams):
+    Tables built lazily (limb slices, BConv tables, Galois permutations)
+    are built at compile time even inside a jit trace, so they stay
+    concrete, cacheable arrays."""
+
+    def __init__(self, params: CkksParams, transform=None):
         self.params = params
+        self.transform = transform
         self.log_n = params.log_n
         self.n = params.n
         self.moduli = params.moduli                       # Q then P
@@ -80,25 +91,39 @@ class CkksContext:
     def limb_tables(self, idx: Sequence[int]) -> nttm.NttTables:
         key = tuple(idx)
         if key not in self._limb_tables_cache:
-            self._limb_tables_cache[key] = self.tables.slice_limbs(list(key))
+            with jax.ensure_compile_time_eval():
+                self._limb_tables_cache[key] = self.tables.slice_limbs(
+                    list(key))
         return self._limb_tables_cache[key]
 
     def bconv_tables(self, src_idx: Sequence[int],
                      dst_idx: Sequence[int]) -> rns.BConvTables:
         key = (tuple(src_idx), tuple(dst_idx))
         if key not in self._bconv_cache:
-            self._bconv_cache[key] = rns.make_bconv_tables(
-                [self.primes[i] for i in key[0]],
-                [self.primes[i] for i in key[1]])
+            with jax.ensure_compile_time_eval():
+                self._bconv_cache[key] = rns.make_bconv_tables(
+                    [self.primes[i] for i in key[0]],
+                    [self.primes[i] for i in key[1]])
         return self._bconv_cache[key]
 
     # -- NTT wrappers over global limb indices ------------------------------
 
     def ntt(self, a: jnp.ndarray, idx: Sequence[int]) -> jnp.ndarray:
+        if self.transform is not None:
+            return self.transform.ntt(a, idx)
         return nttm.ntt(a, self.limb_tables(idx))
 
     def intt(self, a: jnp.ndarray, idx: Sequence[int]) -> jnp.ndarray:
+        if self.transform is not None:
+            return self.transform.intt(a, idx)
         return nttm.intt(a, self.limb_tables(idx))
+
+    def with_transform(self, transform) -> "CkksContext":
+        """This context with its NTTs run by ``transform``; every table
+        and cache is shared with the original."""
+        view = copy.copy(self)
+        view.transform = transform
+        return view
 
     # -- Galois -------------------------------------------------------------
 
@@ -107,7 +132,8 @@ class CkksContext:
         if galois_elt not in self._eval_perm_cache:
             perm = nttm.eval_perm(galois_elt, self.primes[0],
                                   self.tables.psi[0], self.log_n)
-            self._eval_perm_cache[galois_elt] = jnp.asarray(perm)
+            with jax.ensure_compile_time_eval():
+                self._eval_perm_cache[galois_elt] = jnp.asarray(perm)
         return self._eval_perm_cache[galois_elt]
 
     def rotation_element(self, step: int) -> int:

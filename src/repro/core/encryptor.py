@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import Dict, Optional, Sequence
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core import modarith as ma
@@ -130,14 +131,10 @@ class CkksEncryptor:
 
     def encrypt_sk(self, pt: Plaintext, sk: SecretKey) -> Ciphertext:
         idx = self.ctx.q_idx(pt.level)
-        q = self.ctx.q_all[np.array(idx)]
         a = self._sample_uniform_ntt(idx)
         e = self._small_to_ntt(self._sample_error_coeff(), idx)
-        s = sk.s_ntt[np.array(idx)]
-        b = ma.addmod(
-            ma.submod(e, ma.mulmod(a, s, q[:, None]), q[:, None]),
-            pt.data, q[:, None])
-        return Ciphertext(jnp.stack([b, a]), pt.level, pt.scale)
+        return Ciphertext(_sk_encrypt(a, e, sk.s_ntt, pt.data,
+                                      self.ctx.q_all), pt.level, pt.scale)
 
     def encrypt_pk(self, pt: Plaintext, pk: PublicKey) -> Ciphertext:
         ctx = self.ctx
@@ -155,9 +152,27 @@ class CkksEncryptor:
         return Ciphertext(jnp.stack([b, a]), pt.level, pt.scale)
 
     def decrypt(self, ct: Ciphertext, sk: SecretKey) -> Plaintext:
-        idx = self.ctx.q_idx(ct.level)
-        q = self.ctx.q_all[np.array(idx)]
-        s = sk.s_ntt[np.array(idx)]
-        m = ma.addmod(ct.data[0], ma.mulmod(ct.data[1], s, q[:, None]),
-                      q[:, None])
+        m = decrypt_data(ct.data, sk.s_ntt, self.ctx.q_all)
         return Plaintext(m, ct.level, ct.scale)
+
+
+# one compiled program per shape: run op by op, every u64 primitive would
+# be compiled on its own (seconds each on an accelerator)
+
+@jax.jit
+def _sk_encrypt(a, e, s, m, q):
+    """(e - a·s + m, a) over the first L limbs of s and q, L those of a
+    (all NTT domain)."""
+    n_limbs = a.shape[-2]
+    s, q = s[:n_limbs], q[:n_limbs, None]
+    b = ma.addmod(ma.submod(e, ma.mulmod(a, s, q), q), m, q)
+    return jnp.stack([b, a])
+
+
+@jax.jit
+def decrypt_data(data, s, q):
+    """c0 + c1·s over the first L limbs of s and q; data (..., 2, L, N)."""
+    n_limbs = data.shape[-2]
+    s, q = s[:n_limbs], q[:n_limbs, None]
+    return ma.addmod(data[..., 0, :, :],
+                     ma.mulmod(data[..., 1, :, :], s, q), q)

@@ -207,23 +207,36 @@ def coeff_perm(galois_elt: int, n: int) -> Tuple[np.ndarray, np.ndarray]:
     return src, neg
 
 
+def _ntt_forward_host(a: np.ndarray, rp: np.ndarray, p: int) -> np.ndarray:
+    """ntt_forward for one limb in numpy (host), same stages and layout:
+    table construction must not run op by op on an accelerator."""
+    n = len(a)
+    q = np.uint64(p)
+    m = 1
+    while m < n:
+        t = n // (2 * m)
+        a = a.reshape(m, 2 * t)
+        u = a[:, :t]
+        v = (a[:, t:] * rp[m:2 * m, None]) % q
+        a = np.concatenate([(u + v) % q, (u + q - v) % q], axis=1)
+        m *= 2
+    return a.reshape(n)
+
+
 @functools.lru_cache(maxsize=None)
 def _exponent_order_cached(p: int, psi: int, log_n: int) -> tuple:
     """The exponent e_i such that forward-NTT output slot i holds a(psi^{e_i})."""
     n = 1 << log_n
     # NTT of X: slot i = psi^{e_i}
-    import jax.numpy as _jnp
-    x_poly = np.zeros((1, n), dtype=np.uint64)
-    x_poly[0, 1] = 1
+    x_poly = np.zeros(n, dtype=np.uint64)
+    x_poly[1] = 1
     brv = bit_reverse_vector(n)
     pw = np.empty(n, dtype=np.uint64)
     x = 1
     for i in range(n):
         pw[i] = x
         x = x * psi % p
-    rp = _jnp.asarray(pw[brv])[None, :]
-    q = _jnp.asarray(np.array([p], dtype=np.uint64))
-    vals = np.asarray(ntt_forward(_jnp.asarray(x_poly), rp, q))[0]
+    vals = _ntt_forward_host(x_poly, pw[brv], p)
     val_to_exp = {pow(psi, e, p): e for e in range(1, 2 * n, 2)}
     return tuple(val_to_exp[int(v)] for v in vals)
 

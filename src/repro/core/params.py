@@ -127,6 +127,9 @@ class Modulus:
         return bin(self.value).count("1")
 
 
+WORD_BITS = 31    # word32 RNS mode: moduli < 2^31 (DESIGN.md §2)
+
+
 def find_ntt_primes(bits: int, log_n: int, count: int,
                     prefer_solinas: bool = True,
                     exclude: Sequence[int] = ()) -> List[Modulus]:
@@ -134,13 +137,14 @@ def find_ntt_primes(bits: int, log_n: int, count: int,
 
     Solinas-form primes are preferred (paper §IV-B); distinct-bit-width
     neighbours (bits±1) are probed for extra Solinas hits before falling back
-    to generic primes.
+    to generic primes. The probe never goes above WORD_BITS: in word32 mode
+    every modulus is < 2^31, which the u32 Pallas kernels require.
     """
     two_n = 1 << (log_n + 1)
     excl = set(exclude)
     out: List[Modulus] = []
     if prefer_solinas:
-        for b in (bits, bits - 1, bits + 1):
+        for b in (bits, bits - 1, min(bits + 1, WORD_BITS)):
             for p, bb, ss in solinas_candidates(b, log_n + 1):
                 if p not in excl and len(out) < count:
                     out.append(Modulus(p, (bb, ss)))

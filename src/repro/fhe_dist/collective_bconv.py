@@ -52,8 +52,7 @@ def bconv_ring_body(v_local, qhat_inv_local, src_q_local, w_local,
                     dst_q_local, *, axis: str):
     """Ring schedule: rotate the local chunk around the `model` ring,
     accumulating into the local outputs at each hop (chain network)."""
-    from repro.compat import axis_size
-    n_dev = axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     my = jax.lax.axis_index(axis)
     vs = ma.mulmod(v_local, qhat_inv_local[:, None], src_q_local[:, None])
     s_l = vs.shape[0]
@@ -81,12 +80,10 @@ def distributed_bconv(v, qhat_inv, src_q, w, dst_q, mesh: Mesh,
     """
     body = bconv_ring_body if variant == "ring" else bconv_allgather_body
     axis = "model"
-    from repro.compat import shard_map
-    fn = shard_map(
-        partial(body, axis=axis),
-        mesh,
-        (P(axis, None), P(axis), P(axis), P(None, axis), P(axis)),
-        P(axis, None))
+    fn = jax.shard_map(
+        partial(body, axis=axis), mesh=mesh,
+        in_specs=(P(axis, None), P(axis), P(axis), P(None, axis), P(axis)),
+        out_specs=P(axis, None), check_vma=False)
     return fn(v, qhat_inv, src_q, w, dst_q)
 
 

@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-from repro.compat import shard_map as _shard_map
-
-
 def _round_body(x_stack, *, stage_fns: Sequence[Callable], axis: str,
                 n_micro: int):
     """shard_map body over `axis`. x_stack: (n_micro, ...) microbatches,
@@ -69,10 +66,10 @@ def run_pipeline_round(stage_fns: Sequence[Callable], x_stack, mesh: Mesh,
     microbatch stack x_stack (n_micro, ...). len(stage_fns) must equal the
     `axis` size. Returns the processed stack (replicated)."""
     n_micro = x_stack.shape[0]
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_round_body, stage_fns=tuple(stage_fns), axis=axis,
                 n_micro=n_micro),
-        mesh, (P(),), P())
+        mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False)
     return fn(x_stack)
 
 

@@ -29,12 +29,12 @@ from repro.compiler import PassConfig
 from repro.core.params import CkksParams
 from repro.core.pipeline import (MemoryModel, PipelineSchedule,
                                  generate_load_save_pipeline)
-from repro.core.trace import (FheTrace, LevelBudgetExhausted, infer_levels,
-                              trace_program)
+from repro.core.trace import FheTrace
 from repro.fleet.device import Device
 from repro.fleet.router import POLICIES, Router
 from repro.runtime.batcher import BatchPolicy
-from repro.runtime.executor import Workload, resolve_backend
+from repro.runtime.executor import (Workload, resolve_backend,
+                                     workload_trace)
 from repro.runtime.keycache import KeyCache
 from repro.runtime.metrics import MetricsRegistry
 from repro.runtime.queue import Request
@@ -98,15 +98,8 @@ class FleetScheduler:
     def register(self, name: str, fn: Callable, n_inputs: int,
                  const_names: Sequence[str] = (),
                  start_level: int = 10) -> Workload:
-        trace = trace_program(fn, n_inputs, const_names)
-        try:
-            infer_levels(trace, start_level=start_level)
-        except LevelBudgetExhausted:
-            if not (self.pass_config and self.pass_config.bootstrap):
-                raise
-        w = Workload(name, trace)
-        self.workloads[name] = w
-        return w
+        return self.register_trace(name, workload_trace(
+            fn, n_inputs, const_names, start_level, self.pass_config))
 
     def register_trace(self, name: str, trace: FheTrace) -> Workload:
         w = Workload(name, trace)

@@ -10,7 +10,9 @@ Moduli are < 2^31 (word32 RNS mode). Montgomery radix R = 2^32.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 U32 = jnp.uint32
 MASK16 = 0xFFFF  # python int: avoids captured-constant arrays in Pallas kernels
@@ -25,23 +27,52 @@ MASK16 = 0xFFFF  # python int: avoids captured-constant arrays in Pallas kernels
 # how many times the host touches the device per keyswitch, so we count
 # launches at the Python wrapper layer — code already captured inside an
 # enclosing jit trace records at trace time only, which is exactly the
-# steady-state launch count.
+# steady-state launch count. Launches in Pallas interpret mode are also
+# counted apart: on a TPU none may happen (chip_smoke.py checks it), off a
+# TPU they are the only kind.
 
 _dispatch_count = 0
+_interpret_count = 0
 
 
-def record_dispatch(n: int = 1) -> None:
-    global _dispatch_count
+def default_interpret() -> bool:
+    """Kernels compile on a TPU and run in interpret mode elsewhere."""
+    return jax.default_backend() != "tpu"
+
+
+def record_dispatch(n: int = 1, interpret: bool = False) -> None:
+    global _dispatch_count, _interpret_count
     _dispatch_count += n
+    if interpret:
+        _interpret_count += n
 
 
 def dispatch_count() -> int:
     return _dispatch_count
 
 
+def interpret_dispatch_count() -> int:
+    return _interpret_count
+
+
 def reset_dispatch_count() -> None:
-    global _dispatch_count
+    global _dispatch_count, _interpret_count
     _dispatch_count = 0
+    _interpret_count = 0
+
+
+def pallas_call(kernel, **kwargs):
+    """``pl.pallas_call`` traced with 64-bit types off.
+
+    repro.core switches jax_enable_x64 on for its u64 residues; under it
+    the python ints of index maps and kernel bodies lower as i64, which
+    the TPU compiler refuses. Every kernel here is u32/i32 only."""
+    call = pl.pallas_call(kernel, **kwargs)
+
+    def run(*args):
+        with jax.enable_x64(False):
+            return call(*args)
+    return run
 
 
 def mul32_wide(a, b):

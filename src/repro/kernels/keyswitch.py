@@ -39,123 +39,96 @@ and compared in benchmarks/fig14_kernels.py.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+import functools
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (addmod32, mont_mul32, record_dispatch,
-                                  submod32)
+from repro.kernels.common import (addmod32, default_interpret, mont_mul32,
+                                  pallas_call, record_dispatch, submod32)
+from repro.kernels.limb_ntt import (LANES, LimbNttTables, context_tables,
+                                    from_tiles, ntt_fwd, ntt_inv,
+                                    tile_shape, to_tiles)
 
 U32 = jnp.uint32
 U64 = jnp.uint64
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-# ---------------------------------------------------------------------------
-# in-kernel NTT stage helpers (last-axis butterflies, Montgomery twiddles)
-# ---------------------------------------------------------------------------
-
-def _ct_stages_last(x, rp_m, q, qi):
-    """Harvey CT butterflies along the last axis of x (rows, n);
-    rp_m (n,) Montgomery-form twiddles in core/ntt.py bitrev layout."""
-    rows, n = x.shape
-    m = 1
-    while m < n:
-        t = n // (2 * m)
-        xr = x.reshape(rows, m, 2 * t)
-        w = rp_m[m:2 * m]                        # (m,)
-        u = xr[:, :, :t]
-        v = mont_mul32(xr[:, :, t:], w[None, :, None], q, qi)
-        x = jnp.concatenate([addmod32(u, v, q), submod32(u, v, q)],
-                            axis=-1).reshape(rows, n)
-        m *= 2
-    return x
-
-
-def _gs_stages_last(x, irp_m, q, qi):
-    """Gentleman-Sande inverse butterflies (no n^{-1} scale — callers
-    fold it into their own final multiply)."""
-    rows, n = x.shape
-    m = n // 2
-    while m >= 1:
-        t = n // (2 * m)
-        xr = x.reshape(rows, m, 2 * t)
-        w = irp_m[m:2 * m]
-        u = xr[:, :, :t]
-        v = xr[:, :, t:]
-        s = addmod32(u, v, q)
-        d = mont_mul32(submod32(u, v, q), w[None, :, None], q, qi)
-        x = jnp.concatenate([s, d], axis=-1).reshape(rows, n)
-        m //= 2
-    return x
+SQ = pl.squeezed
 
 
 # ---------------------------------------------------------------------------
 # kernel bodies
 # ---------------------------------------------------------------------------
+# Every kernel sees whole (R, C) limb tiles (limb_ntt.py layout). Per-limb
+# scalars (q, -q^{-1}, scales, BConv weights) ride in SMEM as scalar
+# prefetch operands; ``ids`` maps a grid limb to its global prime index,
+# which also selects the limb's twiddle tables in the index maps.
 
-def _intt_scale_kernel(x_ref, irp_ref, q_ref, qi_ref, sc_ref, o_ref):
-    """One (1, 1, N) limb row: inverse NTT fused with a per-limb scale
-    (n^{-1}·qhat^{-1} — the iNTT normalization and the BConv input
-    scaling as ONE Montgomery multiply)."""
-    q = q_ref[0, 0]
-    qi = qi_ref[0, 0]
-    x = _gs_stages_last(x_ref[0], irp_ref[0], q, qi)
-    o_ref[...] = mont_mul32(x, sc_ref[0, 0], q, qi)[None]
+def _intt_scale_kernel(ids_ref, q_ref, qi_ref, sc_ref, x_ref, row_ref,
+                       lane_ref, o_ref):
+    """One limb: inverse NTT fused with a per-limb scale (n^{-1}·qhat^{-1}
+    — the iNTT normalization and the BConv input scaling as ONE
+    Montgomery multiply)."""
+    j = pl.program_id(1)
+    g = ids_ref[j]
+    q = q_ref[g]
+    qi = qi_ref[g]
+    x = ntt_inv(x_ref[...], row_ref, lane_ref, q, qi)
+    o_ref[...] = mont_mul32(x, sc_ref[j], q, qi)
 
 
-def _bconv_ntt_mulacc_kernel(v_ref, w_ref, rp_ref, q_ref, qi_ref,
-                             k0_ref, k1_ref, a0_ref, a1_ref):
-    """One (batch, target-limb) output row, revisited across the digit
-    grid axis: BConv over the digit's (padded) source rows, forward NTT
-    stages fused with their twiddle multiplies, then the evk
-    multiply-accumulate for both key components. Padded source rows
-    carry w = 0 so they contribute nothing."""
-    q = q_ref[0, 0]
-    qi = qi_ref[0, 0]
-    jmax = v_ref.shape[2]
-    n = v_ref.shape[3]
-    acc = jnp.zeros((1, n), U32)
-    for j in range(jmax):                       # adder tree, depth-1
-        prod = mont_mul32(v_ref[0, 0, j, :][None, :], w_ref[0, j, 0], q, qi)
-        acc = addmod32(acc, prod, q)
-    raised = _ct_stages_last(acc, rp_ref[0], q, qi)
-    e0 = mont_mul32(raised, k0_ref[0, 0][None, :], q, qi)
-    e1 = mont_mul32(raised, k1_ref[0, 0][None, :], q, qi)
+def _bconv_ntt_mulacc_kernel(ids_ref, q_ref, qi_ref, w_ref, v_ref, row_ref,
+                             lane_ref, k0_ref, k1_ref, a0_ref, a1_ref):
+    """One (batch, target-limb) output tile, revisited across the digit
+    grid axis: BConv over the digit's (padded) source limbs, forward NTT,
+    then the evk multiply-accumulate for both key components. Padded
+    source limbs carry w = 0 so they contribute nothing."""
+    j = pl.program_id(1)
     d = pl.program_id(2)
+    n_t = pl.num_programs(1)
+    g = ids_ref[j]
+    q = q_ref[g]
+    qi = qi_ref[g]
+    alpha = v_ref.shape[0]
+    acc = jnp.zeros(v_ref.shape[1:], U32)
+    for jj in range(alpha):                     # adder tree, depth-1
+        wgt = w_ref[(d * alpha + jj) * n_t + j]
+        acc = addmod32(acc, mont_mul32(v_ref[jj], wgt, q, qi), q)
+    raised = ntt_fwd(acc, row_ref, lane_ref, q, qi)
+    e0 = mont_mul32(raised, k0_ref[...], q, qi)
+    e1 = mont_mul32(raised, k1_ref[...], q, qi)
 
     @pl.when(d == 0)
     def _():
-        a0_ref[...] = e0[None]
-        a1_ref[...] = e1[None]
+        a0_ref[...] = e0
+        a1_ref[...] = e1
 
     @pl.when(d != 0)
     def _():
-        a0_ref[...] = addmod32(a0_ref[...], e0[None], q)
-        a1_ref[...] = addmod32(a1_ref[...], e1[None], q)
+        a0_ref[...] = addmod32(a0_ref[...], e0, q)
+        a1_ref[...] = addmod32(a1_ref[...], e1, q)
 
 
-def _moddown_kernel(aq_ref, vp_ref, w_ref, rp_ref, q_ref, qi_ref,
-                    pinv_ref, o_ref):
-    """ModDown tail for one (batch, Q-limb) row: BConv P->Q fused with
+def _moddown_kernel(ids_ref, q_ref, qi_ref, w_ref, pinv_ref, aq_ref, vp_ref,
+                    row_ref, lane_ref, o_ref):
+    """ModDown tail for one (batch, Q-limb) tile: BConv P->Q fused with
     the forward NTT, the subtraction from a_Q, and the P^{-1} multiply."""
-    q = q_ref[0, 0]
-    qi = qi_ref[0, 0]
-    n_p = vp_ref.shape[1]
-    n = vp_ref.shape[2]
-    acc = jnp.zeros((1, n), U32)
-    for j in range(n_p):
-        prod = mont_mul32(vp_ref[0, j, :][None, :], w_ref[j, 0], q, qi)
-        acc = addmod32(acc, prod, q)
-    conv = _ct_stages_last(acc, rp_ref[0], q, qi)
-    diff = submod32(aq_ref[0], conv, q)
-    o_ref[...] = mont_mul32(diff, pinv_ref[0, 0], q, qi)[None]
+    j = pl.program_id(1)
+    n_q = pl.num_programs(1)
+    g = ids_ref[j]
+    q = q_ref[g]
+    qi = qi_ref[g]
+    n_p = vp_ref.shape[0]
+    acc = jnp.zeros(vp_ref.shape[1:], U32)
+    for jj in range(n_p):
+        acc = addmod32(acc, mont_mul32(vp_ref[jj], w_ref[jj * n_q + j],
+                                       q, qi), q)
+    conv = ntt_fwd(acc, row_ref, lane_ref, q, qi)
+    diff = submod32(aq_ref[...], conv, q)
+    o_ref[...] = mont_mul32(diff, pinv_ref[j], q, qi)
 
 
 # ---------------------------------------------------------------------------
@@ -170,45 +143,39 @@ def _mont_np(arr: np.ndarray, p: int) -> np.ndarray:
 
 @dataclasses.dataclass
 class _LevelTables:
-    """Device tables for one (level, target basis) instance."""
+    """Per-level scalars of one keyswitch instance (all SMEM-sized)."""
     n_digits: int
     alpha: int                    # padded digit size
     n_p: int
-    # stage A (Q-limb iNTT + digit-local qhat^{-1} scale)
-    q_irp_m: jnp.ndarray          # (L, N)
-    q_q32: jnp.ndarray            # (L,)
-    q_qi32: jnp.ndarray           # (L,)
-    q_scale_m: jnp.ndarray        # (L,)  n^{-1}·qhat^{-1} mont
-    # stage B (target-limb BConv + NTT + evk mulacc)
-    w_m: jnp.ndarray              # (D, alpha, T) mont w.r.t. target prime
-    rp_m: jnp.ndarray             # (T, N) forward twiddles, mont
-    t_q32: jnp.ndarray            # (T,)
-    t_qi32: jnp.ndarray           # (T,)
-    # ModDown
-    p_irp_m: jnp.ndarray          # (n_p, N)
-    p_q32: jnp.ndarray            # (n_p,)
-    p_qi32: jnp.ndarray           # (n_p,)
-    p_scale_m: jnp.ndarray        # (n_p,) n^{-1}·phat^{-1} mont
-    wpq_m: jnp.ndarray            # (n_p, L) mont w.r.t. q
-    pinv_m: jnp.ndarray           # (L,) P^{-1} mod q, mont
+    arrays: Dict[str, jnp.ndarray]
+    # q_ids (L,) i32, q_scale_m (L,): n^{-1}·qhat^{-1} mont
+    # t_ids (T,) i32, w_m (D·alpha·T,): BConv weights mont w.r.t. target
+    # p_ids (n_p,) i32, p_scale_m (n_p,): n^{-1}·phat^{-1} mont
+    # wpq_m (n_p·L,): mont w.r.t. q; pinv_m (L,): P^{-1} mod q, mont
 
 
 class FusedKeySwitch:
     """Executes the fused keyswitch pipeline against one CkksContext.
 
     Tables are built host-side once per level; evaluation keys are
-    Montgomery-converted once per (key identity, level); the whole
+    Montgomery-converted once per key identity; the whole
     4-kernel pipeline is jitted once per (batch, level) and shared by
     every evk (relin and all Galois keys ride the same compiled fn).
+    Tables and keys are arguments of the jitted pipeline, never
+    constants baked into it; ``ntt_tables`` defaults to the context's
+    own (``limb_ntt.context_tables``).
     """
 
     DISPATCHES_PER_APPLY = 4      # pallas_call launches per keyswitch
 
-    def __init__(self, ctx):
+    def __init__(self, ctx, ntt_tables: Optional[LimbNttTables] = None):
         self.ctx = ctx
+        self.ntt_tables = (context_tables(ctx) if ntt_tables is None
+                           else ntt_tables)
         self._tabs: Dict[int, _LevelTables] = {}
         self._ksk_m: Dict[Tuple, jnp.ndarray] = {}
         self._fns: Dict[Tuple, callable] = {}
+        self.n_applied = 0            # keyswitches run through apply()
 
     # -- tables --------------------------------------------------------------
 
@@ -225,17 +192,9 @@ class FusedKeySwitch:
         target = list(range(l)) + ctx.p_idx()
         t_primes = [ctx.primes[i] for i in target]
         t_n = len(target)
-
-        rp = np.asarray(ctx.tables.root_powers)
-        irp = np.asarray(ctx.tables.inv_root_powers)
         n_inv = np.asarray(ctx.tables.n_inv)
 
-        def qinv32(p: int) -> np.uint32:
-            return np.uint32((-pow(p, -1, 1 << 32)) % (1 << 32))
-
-        # stage A: per-Q-limb inverse twiddles + fused n^{-1}·qhat^{-1}
-        q_irp_m = np.stack([_mont_np(irp[j], ctx.primes[j])
-                            for j in range(l)])
+        # stage A: fused n^{-1}·qhat^{-1} per Q limb
         q_scale = np.zeros(l, dtype=np.uint32)
         for dig in digits:
             big_qd = 1
@@ -247,7 +206,7 @@ class FusedKeySwitch:
                 sc = int(n_inv[j]) * qhat_inv % qj
                 q_scale[j] = _mont_np(np.array([sc], dtype=np.uint64), qj)[0]
 
-        # stage B: BConv weights per (digit, src, target) + fwd twiddles
+        # stage B: BConv weights per (digit, src, target)
         w = np.zeros((d_n, alpha, t_n), dtype=np.uint32)
         for d, dig in enumerate(digits):
             big_qd = 1
@@ -258,11 +217,9 @@ class FusedKeySwitch:
                 for ti, p in enumerate(t_primes):
                     w[d, jl, ti] = _mont_np(
                         np.array([qhat % p], dtype=np.uint64), p)[0]
-        rp_m = np.stack([_mont_np(rp[g], ctx.primes[g]) for g in target])
 
-        # ModDown: P-limb iNTT + n^{-1}·phat^{-1}, BConv P->Q, P^{-1}
+        # ModDown: n^{-1}·phat^{-1} per P limb, BConv P->Q, P^{-1}
         p_glob = ctx.p_idx()
-        p_irp_m = np.stack([_mont_np(irp[g], ctx.primes[g]) for g in p_glob])
         big_p = ctx.big_p
         p_scale = np.zeros(n_p, dtype=np.uint32)
         wpq = np.zeros((n_p, l), dtype=np.uint32)
@@ -275,141 +232,120 @@ class FusedKeySwitch:
                 qj = ctx.primes[j]
                 wpq[i, j] = _mont_np(np.array([phat % qj],
                                               dtype=np.uint64), qj)[0]
-        pinv = np.asarray(ctx.p_inv_mod_q[:l])
+        pinv = np.asarray(ctx.p_inv_mod_q)[:l]
         pinv_m = np.array([_mont_np(pinv[j:j + 1], ctx.primes[j])[0]
                            for j in range(l)], dtype=np.uint32)
 
-        t = _LevelTables(
-            n_digits=d_n, alpha=alpha, n_p=n_p,
-            q_irp_m=jnp.asarray(q_irp_m),
-            q_q32=jnp.asarray(np.array(ctx.primes[:l], dtype=np.uint32)),
-            q_qi32=jnp.asarray(np.array(
-                [qinv32(ctx.primes[j]) for j in range(l)], dtype=np.uint32)),
-            q_scale_m=jnp.asarray(q_scale),
-            w_m=jnp.asarray(w),
-            rp_m=jnp.asarray(rp_m),
-            t_q32=jnp.asarray(np.array(t_primes, dtype=np.uint32)),
-            t_qi32=jnp.asarray(np.array([qinv32(p) for p in t_primes],
-                                        dtype=np.uint32)),
-            p_irp_m=jnp.asarray(p_irp_m),
-            p_q32=jnp.asarray(np.array([ctx.primes[g] for g in p_glob],
-                                       dtype=np.uint32)),
-            p_qi32=jnp.asarray(np.array(
-                [qinv32(ctx.primes[g]) for g in p_glob], dtype=np.uint32)),
-            p_scale_m=jnp.asarray(p_scale),
-            wpq_m=jnp.asarray(wpq),
-            pinv_m=jnp.asarray(pinv_m),
-        )
+        arrays = dict(
+            q_ids=np.arange(l, dtype=np.int32), q_scale_m=q_scale,
+            t_ids=np.array(target, dtype=np.int32), w_m=w.reshape(-1),
+            p_ids=np.array(p_glob, dtype=np.int32), p_scale_m=p_scale,
+            wpq_m=wpq.reshape(-1), pinv_m=pinv_m)
+        t = _LevelTables(n_digits=d_n, alpha=alpha, n_p=n_p,
+                         arrays={k: jnp.asarray(v) for k, v in arrays.items()})
         self._tabs[level] = t
         return t
 
-    def ksk_mont(self, key: Tuple, level: int,
-                 ksk_data: jnp.ndarray) -> jnp.ndarray:
-        """Target-basis slice of an evk in Montgomery form, cached per
-        (stable key identity, level): (D, 2, T, N) u32."""
-        k = (key, level)
-        m = self._ksk_m.get(k)
-        if m is not None:
-            return m
-        from repro.core import modarith as ma
-        ctx = self.ctx
-        t = self._tables(level)
-        target = np.array(list(range(level + 1)) + ctx.p_idx())
-        q_t = ctx.q_all[target][:, None]
-        rm = jnp.asarray(np.array(
-            [(1 << 32) % ctx.primes[g] for g in target], dtype=np.uint64))
-        sel = ksk_data[: t.n_digits, :, target]
-        m = ma.mulmod(sel, rm[:, None], q_t).astype(U32)
-        self._ksk_m[k] = m
+    def ksk_mont(self, key: Tuple, ksk_data: jnp.ndarray) -> jnp.ndarray:
+        """An evk over the whole basis in Montgomery form, cached per
+        stable key identity: (dnum, 2, n_all, R, C) u32. Every level's
+        pipeline reads its digits and target limbs from it in place."""
+        m = self._ksk_m.get(key)
+        if m is None:
+            rm = np.array([(1 << 32) % p for p in self.ctx.primes],
+                          dtype=np.uint64)
+            m = self._ksk_m[key] = _ksk_to_mont(
+                ksk_data, rm, np.array(self.ctx.primes, dtype=np.uint64))
         return m
 
     # -- pipeline ------------------------------------------------------------
 
     def _build(self, b: int, level: int, itp: bool):
-        """The full 4-kernel pipeline for one (batch, level) signature."""
+        """The full 4-kernel pipeline for one (batch, level) signature:
+        ``run(d2 (b, l, N) u64, ksk_m, nt, lv) -> (e0, e1)`` where ``nt``
+        is the context's LimbNttTables and ``lv`` this level's arrays."""
         t = self._tables(level)
         l = level + 1
-        n = self.ctx.n
+        r_n, c_n = tile_shape(self.ctx.n)
         d_n, alpha, n_p = t.n_digits, t.alpha, t.n_p
         t_n = l + n_p
+        s_n = self.ctx.log_n
+        tile = (r_n, c_n)
+        row_tab = (SQ, r_n, LANES)
+        lane_tab = (SQ, s_n, 1, c_n)
 
-        def run(d2, ksk_m):
-            d2 = d2.astype(U32)
-            row3 = lambda i, j: (i, j, 0)                     # noqa: E731
-            limb_row = lambda i, j: (j, 0)                    # noqa: E731
-            limb_scal = lambda i, j: (j, 0)                   # noqa: E731
+        # twiddle tiles of the limb that grid axis 1 maps to (ids: the
+        # first scalar-prefetch operand)
+        tabs = [pl.BlockSpec(row_tab, lambda i, j, ids, *_: (ids[j], 0, 0)),
+                pl.BlockSpec(lane_tab,
+                             lambda i, j, ids, *_: (ids[j], 0, 0, 0))]
+
+        def grid_spec(n_prefetch, grid, in_specs, out_specs):
+            return pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=n_prefetch, grid=grid,
+                in_specs=in_specs, out_specs=out_specs)
+
+        def call(kernel, spec, out_shape):
+            return pallas_call(kernel, grid_spec=spec, out_shape=out_shape,
+                               interpret=itp)
+
+        def run(d2, ksk_m, nt, lv):
+            x = to_tiles(d2)                                  # (b, l, R, C)
+            limb_out = jax.ShapeDtypeStruct((b, l) + tile, U32)
             # A: ModUp front half for every digit limb at once
-            v = pl.pallas_call(
-                _intt_scale_kernel,
-                grid=(b, l),
-                in_specs=[pl.BlockSpec((1, 1, n), row3),
-                          pl.BlockSpec((1, n), limb_row),
-                          pl.BlockSpec((1, 1), limb_scal),
-                          pl.BlockSpec((1, 1), limb_scal),
-                          pl.BlockSpec((1, 1), limb_scal)],
-                out_specs=pl.BlockSpec((1, 1, n), row3),
-                out_shape=jax.ShapeDtypeStruct((b, l, n), U32),
-                interpret=itp,
-            )(d2, t.q_irp_m, t.q_q32[:, None], t.q_qi32[:, None],
-              t.q_scale_m[:, None])
+            v = call(_intt_scale_kernel, grid_spec(
+                4, (b, l),
+                [pl.BlockSpec((SQ, SQ) + tile,
+                              lambda i, j, *_: (i, j, 0, 0))] + tabs,
+                pl.BlockSpec((SQ, SQ) + tile, lambda i, j, *_: (i, j, 0, 0))),
+                limb_out)(lv["q_ids"], nt.q32, nt.qinv32, lv["q_scale_m"],
+                          x, nt.inv_row, nt.inv_lane)
             # digits partition the Q limbs contiguously in alpha-chunks:
             # zero-pad the tail digit and fold the digit axis out
-            v_pad = jnp.pad(v, ((0, 0), (0, d_n * alpha - l),
-                                (0, 0))).reshape(b, d_n, alpha, n)
+            v_pad = jnp.pad(v, ((0, 0), (0, d_n * alpha - l), (0, 0), (0, 0))
+                            ).reshape((b, d_n, alpha) + tile)
             # B: on-chip digit loop (digit axis innermost -> accumulator
-            # blocks stay resident across digits)
-            acc0, acc1 = pl.pallas_call(
-                _bconv_ntt_mulacc_kernel,
-                grid=(b, t_n, d_n),
-                in_specs=[
-                    pl.BlockSpec((1, 1, alpha, n),
-                                 lambda i, j, d: (i, d, 0, 0)),
-                    pl.BlockSpec((1, alpha, 1), lambda i, j, d: (d, 0, j)),
-                    pl.BlockSpec((1, n), lambda i, j, d: (j, 0)),
-                    pl.BlockSpec((1, 1), lambda i, j, d: (j, 0)),
-                    pl.BlockSpec((1, 1), lambda i, j, d: (j, 0)),
-                    pl.BlockSpec((1, 1, n), lambda i, j, d: (d, j, 0)),
-                    pl.BlockSpec((1, 1, n), lambda i, j, d: (d, j, 0)),
-                ],
-                out_specs=[pl.BlockSpec((1, 1, n), lambda i, j, d: (i, j, 0)),
-                           pl.BlockSpec((1, 1, n),
-                                        lambda i, j, d: (i, j, 0))],
-                out_shape=[jax.ShapeDtypeStruct((b, t_n, n), U32),
-                           jax.ShapeDtypeStruct((b, t_n, n), U32)],
-                interpret=itp,
-            )(v_pad, t.w_m, t.rp_m, t.t_q32[:, None], t.t_qi32[:, None],
-              ksk_m[:, 0], ksk_m[:, 1])
+            # tiles stay resident across digits)
+            acc_out = jax.ShapeDtypeStruct((b, t_n) + tile, U32)
+            acc_spec = pl.BlockSpec((SQ, SQ) + tile,
+                                    lambda i, j, d, *_: (i, j, 0, 0))
+            acc0, acc1 = call(_bconv_ntt_mulacc_kernel, grid_spec(
+                4, (b, t_n, d_n),
+                [pl.BlockSpec((SQ, SQ, alpha) + tile,
+                              lambda i, j, d, *_: (i, d, 0, 0, 0)),
+                 pl.BlockSpec(row_tab,
+                              lambda i, j, d, ids, *_: (ids[j], 0, 0)),
+                 pl.BlockSpec(lane_tab,
+                              lambda i, j, d, ids, *_: (ids[j], 0, 0, 0)),
+                 pl.BlockSpec((SQ, SQ, SQ) + tile,
+                              lambda i, j, d, ids, *_: (d, 0, ids[j], 0, 0)),
+                 pl.BlockSpec((SQ, SQ, SQ) + tile,
+                              lambda i, j, d, ids, *_: (d, 1, ids[j], 0, 0))],
+                [acc_spec, acc_spec]),
+                [acc_out, acc_out])(lv["t_ids"], nt.q32, nt.qinv32,
+                                    lv["w_m"], v_pad, nt.fwd_row,
+                                    nt.fwd_lane, ksk_m, ksk_m)
             # ModDown: both key components fold into the batch axis
-            g = jnp.concatenate([acc0, acc1], axis=0)         # (2b, T, n)
-            vp = pl.pallas_call(
-                _intt_scale_kernel,
-                grid=(2 * b, n_p),
-                in_specs=[pl.BlockSpec((1, 1, n), lambda i, j: (i, l + j, 0)),
-                          pl.BlockSpec((1, n), limb_row),
-                          pl.BlockSpec((1, 1), limb_scal),
-                          pl.BlockSpec((1, 1), limb_scal),
-                          pl.BlockSpec((1, 1), limb_scal)],
-                out_specs=pl.BlockSpec((1, 1, n), row3),
-                out_shape=jax.ShapeDtypeStruct((2 * b, n_p, n), U32),
-                interpret=itp,
-            )(g, t.p_irp_m, t.p_q32[:, None], t.p_qi32[:, None],
-              t.p_scale_m[:, None])
-            out = pl.pallas_call(
-                _moddown_kernel,
-                grid=(2 * b, l),
-                in_specs=[pl.BlockSpec((1, 1, n), row3),
-                          pl.BlockSpec((1, n_p, n), lambda i, j: (i, 0, 0)),
-                          pl.BlockSpec((n_p, 1), lambda i, j: (0, j)),
-                          pl.BlockSpec((1, n), limb_row),
-                          pl.BlockSpec((1, 1), limb_scal),
-                          pl.BlockSpec((1, 1), limb_scal),
-                          pl.BlockSpec((1, 1), limb_scal)],
-                out_specs=pl.BlockSpec((1, 1, n), row3),
-                out_shape=jax.ShapeDtypeStruct((2 * b, l, n), U32),
-                interpret=itp,
-            )(g, vp, t.wpq_m, t.rp_m, t.t_q32[:, None], t.t_qi32[:, None],
-              t.pinv_m[:, None])
-            return out[:b].astype(U64), out[b:].astype(U64)
+            g = jnp.concatenate([acc0, acc1], axis=0)        # (2b, T, R, C)
+            vp = call(_intt_scale_kernel, grid_spec(
+                4, (2 * b, n_p),
+                [pl.BlockSpec((SQ, SQ) + tile,
+                              lambda i, j, *_: (i, l + j, 0, 0))] + tabs,
+                pl.BlockSpec((SQ, SQ) + tile, lambda i, j, *_: (i, j, 0, 0))),
+                jax.ShapeDtypeStruct((2 * b, n_p) + tile, U32))(
+                    lv["p_ids"], nt.q32, nt.qinv32, lv["p_scale_m"],
+                    g, nt.inv_row, nt.inv_lane)
+            out = call(_moddown_kernel, grid_spec(
+                5, (2 * b, l),
+                [pl.BlockSpec((SQ, SQ) + tile, lambda i, j, *_: (i, j, 0, 0)),
+                 pl.BlockSpec((SQ, n_p) + tile,
+                              lambda i, j, *_: (i, 0, 0, 0))] + tabs,
+                pl.BlockSpec((SQ, SQ) + tile, lambda i, j, *_: (i, j, 0, 0))),
+                jax.ShapeDtypeStruct((2 * b, l) + tile, U32))(
+                    lv["q_ids"], nt.q32, nt.qinv32, lv["wpq_m"],
+                    lv["pinv_m"], g, vp, nt.fwd_row, nt.fwd_lane)
+            shape = (b, l, r_n * c_n)
+            return from_tiles(out[:b], shape), from_tiles(out[b:], shape)
         return run
 
     def apply(self, d2: jnp.ndarray, level: int, ksk_m: jnp.ndarray,
@@ -417,24 +353,25 @@ class FusedKeySwitch:
         """Key-switch d2 (B, level+1, N) u64 NTT-domain to the key in
         ksk_m (from ``ksk_mont``). Returns (e0, e1), each (B, level+1,
         N) u64 — bit-identical to core/ops.key_switch per batch row."""
-        itp = _default_interpret() if interpret is None else interpret
+        itp = default_interpret() if interpret is None else interpret
         b = d2.shape[0]
         key = (b, level, itp)
         fn = self._fns.get(key)
         if fn is None:
-            # first call runs un-jitted (the pallas interpreter traces
-            # eagerly; tables must land as concrete arrays), then the
-            # jitted pipeline is cached — the steady state is ONE fused
-            # XLA program containing the 4 kernel launches
-            eager = self._build(b, level, itp)
+            # one fused XLA program holding the 4 kernel launches
+            fn = self._fns[key] = jax.jit(self._build(b, level, itp))
+        record_dispatch(self.DISPATCHES_PER_APPLY, interpret=itp)
+        self.n_applied += 1
+        return fn(d2, ksk_m, self.ntt_tables, self._tables(level).arrays)
 
-            def first(d2_, ksk_):
-                out = eager(d2_, ksk_)
-                self._fns[key] = jax.jit(eager)
-                return out
-            fn = first
-        record_dispatch(self.DISPATCHES_PER_APPLY)
-        return fn(d2, ksk_m)
+
+@jax.jit
+def _ksk_to_mont(ksk_data, rm, q):
+    """(dnum, 2, n_all, N) u64 evk -> (dnum, 2, n_all, R, C) u32 Montgomery."""
+    from repro.core import modarith as ma
+    m = ma.mulmod(ksk_data, rm[:, None], q[:, None])
+    return to_tiles(m).reshape(ksk_data.shape[:3] +
+                               tile_shape(ksk_data.shape[-1]))
 
 
 # ---------------------------------------------------------------------------
@@ -450,7 +387,7 @@ def keyswitch_staged(ctx, d2: jnp.ndarray, level: int, ksk,
     7 per digit (iNTT, qhat^{-1} modmul, BConv, NTT, interleave, 2×evk
     mulacc) plus 10 for ModDown."""
     from repro.kernels import ops as kops
-    itp = _default_interpret() if interpret is None else interpret
+    itp = default_interpret() if interpret is None else interpret
     idx_q = ctx.q_idx(level)
     idx_p = ctx.p_idx()
     target = idx_q + idx_p

@@ -16,13 +16,9 @@ import jax.numpy as jnp
 from repro.core import modarith as ma
 from repro.kernels import bconv as bconv_k
 from repro.kernels import modmul as modmul_k
-from repro.kernels.common import record_dispatch
+from repro.kernels.common import default_interpret, record_dispatch
 from repro.kernels.ntt import FourStepKernelTables, ntt_four_step_pallas
 from repro.kernels.ref import FourStepTables
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @lru_cache(maxsize=256)
@@ -59,8 +55,8 @@ def _modmul_impl(a, b, q64, q32, qinv, rm, interpret=True):
 def modmul(a, b, primes: Sequence[int], interpret=None):
     """(a*b) mod q per limb. a, b: (L, N) u64; primes: python ints."""
     q64, q32, qinv, rm = _mont_consts(_key(primes))
-    itp = _default_interpret() if interpret is None else interpret
-    record_dispatch()
+    itp = default_interpret() if interpret is None else interpret
+    record_dispatch(interpret=itp)
     return _modmul_impl(a, b, q64, q32, qinv, rm, interpret=itp)
 
 
@@ -75,8 +71,8 @@ def _mulacc_impl(a, b, c, q64, q32, qinv, rm, interpret=True):
 def mulacc(a, b, c, primes: Sequence[int], interpret=None):
     """(a*b + c) mod q per limb."""
     q64, q32, qinv, rm = _mont_consts(_key(primes))
-    itp = _default_interpret() if interpret is None else interpret
-    record_dispatch()
+    itp = default_interpret() if interpret is None else interpret
+    record_dispatch(interpret=itp)
     return _mulacc_impl(a, b, c, q64, q32, qinv, rm, interpret=itp)
 
 
@@ -96,8 +92,8 @@ def bconv(v, w, dst_primes: Sequence[int], lazy: bool = False,
           interpret=None):
     """out[d] = sum_j v[j]*w[j,d] mod p_d. v: (S,N) u64; w: (S,D) u64."""
     p64, p32, pinv, rm64 = _mont_consts(_key(dst_primes))
-    itp = _default_interpret() if interpret is None else interpret
-    record_dispatch()
+    itp = default_interpret() if interpret is None else interpret
+    record_dispatch(interpret=itp)
     return _bconv_impl(v, w.T, p64, p32, pinv, rm64, lazy=lazy,
                        interpret=itp)
 
@@ -110,8 +106,8 @@ class NttKernel:
         self.kt = FourStepKernelTables(self.tabs)
 
     def __call__(self, a, interpret=None, **blocks):
-        itp = _default_interpret() if interpret is None else interpret
-        record_dispatch(2)          # column kernel + fused row kernel
+        itp = default_interpret() if interpret is None else interpret
+        record_dispatch(2, interpret=itp)          # column kernel + fused row kernel
         return ntt_four_step_pallas(a.astype(jnp.uint32), self.kt,
                                     interpret=itp,
                                     **blocks).astype(jnp.uint64)

@@ -23,7 +23,6 @@ import time  # noqa: E402
 import traceback  # noqa: E402
 
 import jax  # noqa: E402
-from repro.compat import set_mesh as compat_set_mesh  # noqa: E402
 
 from repro.configs import list_archs  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
@@ -78,7 +77,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool) -> dict:
         return rec
     t0 = time.time()
     try:
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(cell["fn"], in_shardings=cell["in_shardings"],
                              out_shardings=cell["out_shardings"])
             lowered = jitted.lower(*cell["args"])

@@ -9,15 +9,20 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh as _make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over local devices (smoke tests / examples)."""
-    return _make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
+
+
+def make_mesh(shape, axes):
+    """jax.make_mesh with every axis Auto-sharded."""
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))

@@ -27,7 +27,6 @@ import traceback  # noqa: E402
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
-from repro.compat import set_mesh as compat_set_mesh  # noqa: E402
 
 from repro.configs import get_config, DASHED  # noqa: E402
 from repro.launch.dryrun import RESULTS_DIR, collective_bytes  # noqa: E402
@@ -89,7 +88,7 @@ def _measure(cfg, shape: str, mesh) -> dict:
     """Lower+compile one unrolled config; return flops/bytes/collectives."""
     cell = build_cell(cfg.name, shape, mesh, cfg_override=cfg)
     assert not cell["skip"], cell.get("reason")
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell["fn"], in_shardings=cell["in_shardings"],
                          out_shardings=cell["out_shardings"])
         lowered = jitted.lower(*cell["args"])

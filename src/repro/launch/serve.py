@@ -10,7 +10,6 @@ import time
 
 import numpy as np
 import jax
-from repro.compat import set_mesh as compat_set_mesh
 import jax.numpy as jnp
 
 from repro.configs import get_config
@@ -34,7 +33,7 @@ def main():
     b = args.batch
     s_max = args.prompt_len + args.gen
 
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         serve = jax.jit(M.make_serve_step(cfg, mesh))
         cache = M.init_cache(cfg, b, s_max)
         if cfg.enc_dec:

@@ -33,14 +33,17 @@ constants.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
+import time
+from typing import Dict, List, Optional
 
 import numpy as np
 
 from repro.compiler import PassConfig
 from repro.core.params import CkksParams, test_params
 from repro.core.pipeline import MemoryModel
-from repro.core.trace import LevelBudgetExhausted
+from repro.core.trace import FheTrace, LevelBudgetExhausted
 from repro.pim.arch import PRESETS as PIM_PRESETS
 from repro.pim.arch import memory_model as pim_memory_model
 from repro.fleet.router import POLICIES as ROUTER_POLICIES
@@ -63,6 +66,25 @@ WORKLOADS = {
 }
 
 
+def workload_traces(start_level: int, pass_config: Optional[PassConfig],
+                    quiet: bool = False) -> Dict[str, FheTrace]:
+    """The registered workloads' traces at ``start_level``; a workload
+    deeper than that without bootstrap insertion is skipped (with a note
+    unless ``quiet``)."""
+    from repro.runtime.executor import workload_trace
+    out = {}
+    for name, (fn, n_in, consts) in WORKLOADS.items():
+        try:
+            out[name] = workload_trace(fn, n_in, consts, start_level,
+                                       pass_config)
+        except LevelBudgetExhausted:
+            if not quiet:
+                print(f"skipping workload {name!r}: deeper than "
+                      f"start_level={start_level} and --no-opt "
+                      f"disables automatic bootstrap insertion")
+    return out
+
+
 def build_executor(params: CkksParams, mem: MemoryModel, *,
                    backend_name: str, max_batch: int, max_wait_s: float,
                    cache_bytes: int, start_level: int,
@@ -80,14 +102,8 @@ def build_executor(params: CkksParams, mem: MemoryModel, *,
                            key_cache=key_cache,
                            pass_config=PassConfig() if opt else None,
                            verify=verify)
-    for name, (fn, n_in, consts) in WORKLOADS.items():
-        try:
-            ex.register(name, fn, n_in, const_names=consts,
-                        start_level=start_level)
-        except LevelBudgetExhausted:
-            print(f"skipping workload {name!r}: deeper than "
-                  f"start_level={start_level} and --no-opt disables "
-                  f"automatic bootstrap insertion")
+    for name, trace in workload_traces(start_level, ex.pass_config).items():
+        ex.register_trace(name, trace)
     return ex
 
 
@@ -114,14 +130,9 @@ def build_fleet_scheduler(params: CkksParams, mem: MemoryModel, *,
         pass_config=PassConfig() if opt else None,
         continuous_batching=continuous_batching, preempt=preempt,
         verify=verify)
-    for name, (fn, n_in, consts) in WORKLOADS.items():
-        try:
-            fleet.register(name, fn, n_in, const_names=consts,
-                           start_level=start_level)
-        except LevelBudgetExhausted:
-            print(f"skipping workload {name!r}: deeper than "
-                  f"start_level={start_level} and --no-opt disables "
-                  f"automatic bootstrap insertion")
+    for name, trace in workload_traces(start_level,
+                                       fleet.pass_config).items():
+        fleet.register_trace(name, trace)
     return fleet
 
 
@@ -175,7 +186,33 @@ def synth_arrivals(ex, *, n_tenants: int, n_requests: int,
     return arrivals
 
 
-def main() -> None:
+# key-cache room for plaintext constants (LRU) beyond the pinned keys
+CONST_CACHE_MB = 256
+
+
+@dataclasses.dataclass
+class ServeResult:
+    """What one serve_fhe run produced; ``failures`` empty means OK."""
+    executor: object
+    metrics: object
+    params: CkksParams
+    warmup_s: float
+    serve_s: float
+    failures: List[str]
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    from repro.launch.jax_cache import enable_compile_cache
+    enable_compile_cache()
+    res = run(argv)
+    for f in res.failures:
+        print(f"FAILED: {f}", file=sys.stderr)
+    if res.failures:
+        sys.exit(1)
+
+
+def run(argv: Optional[List[str]] = None) -> ServeResult:
+    """Parse serve_fhe's arguments, serve, and check the outcome."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small params, few requests, fast end-to-end check")
@@ -214,8 +251,12 @@ def main() -> None:
     ap.add_argument("--max-wait-ms", type=float, default=2.0)
     ap.add_argument("--deadline-ms", type=float, default=200.0,
                     help="per-request deadline; 0 disables")
-    ap.add_argument("--cache-mb", type=int, default=256,
-                    help="key cache capacity; 0 disables the cache")
+    ap.add_argument("--cache-mb", type=int, default=None,
+                    help="key cache capacity; 0 disables the cache. "
+                         "Default: 256, and for --backend ciphertext the "
+                         "pinned evaluation keys of the registered "
+                         "workloads plus 256 (a smaller explicit value "
+                         "fails at startup with the size needed)")
     ap.add_argument("--no-encrypt", action="store_true",
                     help="skip real CKKS payload encryption at ingest")
     ap.add_argument("--use-kernels", action=argparse.BooleanOptionalAction,
@@ -223,8 +264,9 @@ def main() -> None:
                     help="(--backend ciphertext) route keyswitch + modmul "
                          "through the fused Pallas kernels "
                          "(repro.kernels.keyswitch; bit-exact vs the "
-                         "library path, compiled on TPU / interpret mode "
-                         "on CPU); default: on iff running on TPU")
+                         "library path); the kernels compile on a TPU and "
+                         "run in interpret mode anywhere else; default: "
+                         "on iff running on a TPU")
     ap.add_argument("--verify", action="store_true",
                     help="static verification (repro.analysis): sweep "
                          "every freshly compiled schedule (per-pass "
@@ -255,7 +297,7 @@ def main() -> None:
                          "event (accepted/routed/preempted/completed/"
                          "dropped...) to stdout as it happens")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.smoke:
         args.requests = min(args.requests, 60)
@@ -270,6 +312,13 @@ def main() -> None:
             params = test_params(log_n=8, n_levels=8, dnum=2)
             args.deadline_ms = 0.0
     else:
+        if args.backend == "ciphertext":
+            import jax
+            if jax.default_backend() != "tpu":
+                ap.error("--backend ciphertext at the paper's parameters "
+                         "(logN=16) runs on a TPU, and JAX found none "
+                         f"(default backend: {jax.default_backend()}); "
+                         "--smoke is the CPU-sized check")
         from repro.core.params import paper_params_bootstrap
         params = paper_params_bootstrap()
         start_level = 20
@@ -294,12 +343,29 @@ def main() -> None:
     # are encrypted under the serving keys at pack time), so the
     # synthetic foreign-key ciphertext wrapping is redundant there
     encrypt = not args.no_encrypt and args.backend != "ciphertext"
+    cache_mb = CONST_CACHE_MB if args.cache_mb is None else args.cache_mb
+    if args.backend == "ciphertext" and cache_mb > 0:
+        # every evaluation key is pinned: size the cache for them (or
+        # refuse an explicit size that cannot hold them) before warmup
+        from repro.runtime.ciphertext_backend import CiphertextBackend
+        pass_config = PassConfig() if args.opt else None
+        n_keys, key_bytes = CiphertextBackend.pinned_key_bytes(
+            workload_traces(start_level, pass_config, quiet=True).values(),
+            params, pass_config)
+        need_mb = -(-key_bytes // 2 ** 20)
+        if args.cache_mb is None:
+            cache_mb = need_mb + CONST_CACHE_MB
+        elif cache_mb < need_mb:
+            ap.error(f"--cache-mb {cache_mb} cannot hold the {n_keys} "
+                     f"evaluation keys the registered workloads pin "
+                     f"({need_mb} MiB at logN={params.log_n}); pass "
+                     f"--cache-mb {need_mb + CONST_CACHE_MB} or more")
     if args.fleet > 0:
         ex = build_fleet_scheduler(
             params, mem, n_devices=args.fleet, backend_name=args.backend,
             router=args.router, max_batch=args.max_batch,
             max_wait_s=args.max_wait_ms * 1e-3,
-            cache_bytes=args.cache_mb * 2 ** 20,
+            cache_bytes=cache_mb * 2 ** 20,
             start_level=start_level, opt=args.opt,
             continuous_batching=args.continuous_batching,
             preempt=args.preempt, use_kernels=args.use_kernels,
@@ -308,7 +374,7 @@ def main() -> None:
         ex = build_executor(params, mem, backend_name=args.backend,
                             max_batch=args.max_batch,
                             max_wait_s=args.max_wait_ms * 1e-3,
-                            cache_bytes=args.cache_mb * 2 ** 20,
+                            cache_bytes=cache_mb * 2 ** 20,
                             start_level=start_level, opt=args.opt,
                             use_kernels=args.use_kernels,
                             verify=args.verify)
@@ -318,7 +384,7 @@ def main() -> None:
         deadline_s=args.deadline_ms * 1e-3,
         encrypt=encrypt, max_slots=min(128, params.slots))
 
-    cache_tag = "off" if args.cache_mb <= 0 else f"{args.cache_mb}MiB"
+    cache_tag = "off" if cache_mb <= 0 else f"{cache_mb}MiB"
     fleet_tag = (f"fleet of {args.fleet} ({args.router} router"
                  f"{', continuous batching' if args.continuous_batching else ''}"
                  f"{', preemption' if args.preempt else ''}), "
@@ -326,11 +392,10 @@ def main() -> None:
     print(f"serving {len(arrivals)} requests from {args.tenants} tenants "
           f"({fleet_tag}{args.backend} backend, key cache {cache_tag}, "
           f"compiler {'on' if args.opt else 'off'})")
-    import time as _time
-    t0 = _time.perf_counter()
+    t0 = time.perf_counter()
     ex.warmup()
-    print(f"warmup (compile + key preload): "
-          f"{_time.perf_counter() - t0:.2f} s")
+    warmup_s = time.perf_counter() - t0
+    print(f"warmup (compile + key preload): {warmup_s:.2f} s")
     # observability: the tracer/event log hang off the shared registry
     # (fleet devices all share ex.metrics), attached after warmup so
     # deploy-time work stays out of the serving trace
@@ -349,7 +414,9 @@ def main() -> None:
             clock="wall" if wall else "virtual")
         if args.deadline_ms > 0:
             ex.metrics.slo = SloBurnRate()
+    t0 = time.perf_counter()
     m = ex.serve(arrivals)
+    serve_s = time.perf_counter() - t0
     print(m.format_table())
     if args.verify:
         # warmup compiles point metrics at a scratch registry, so the
@@ -391,21 +458,24 @@ def main() -> None:
               f"({telemetry.n_points()} points, {n} samples, "
               f"{telemetry.clock} clock{slo_tag}) -> {args.metrics_out}")
 
+    failures: List[str] = []
     if args.backend == "ciphertext":
         tol = (ex.devices[0].backend if args.fleet > 0
                else ex.backend).tolerance
-        failed = False
         for w in ex.workloads:
             err = m.decrypt_error.get(w)
             if err is None:
-                print(f"accuracy {w:<12} no batch served")
+                print(f"accuracy {w:<12} no batch served FAIL")
+                failures.append(f"workload {w!r} served no batch")
                 continue
             ok = err <= tol
-            failed |= not ok
-            print(f"accuracy {w:<12} max|err|={err:.3e} "
-                  f"tol={tol:.3e} {'OK' if ok else 'FAIL'}")
-        if failed:
-            sys.exit(1)
+            if not ok:
+                failures.append(f"workload {w!r} max|err|={err:.3e} "
+                                f"exceeds tol={tol:.3e}")
+            print(f"accuracy {w:<12} batches={m.decrypt_batches[w]} "
+                  f"max|err|={err:.3e} tol={tol:.3e} "
+                  f"{'OK' if ok else 'FAIL'}")
+    return ServeResult(ex, m, params, warmup_s, serve_s, failures)
 
 
 if __name__ == "__main__":

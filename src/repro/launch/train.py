@@ -12,7 +12,6 @@ import os
 import time
 
 import jax
-from repro.compat import set_mesh as compat_set_mesh
 
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLMDataset, shard_batch
@@ -46,7 +45,7 @@ def main():
     opt_state = adamw_init(params)
     ds = SyntheticLMDataset(cfg, args.batch, args.seq)
 
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn = jax.jit(M.make_train_step(cfg, mesh,
                                             learning_rate=args.lr))
         start = 0

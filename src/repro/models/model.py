@@ -329,13 +329,11 @@ def _moe_layer(x, bp, cfg, mesh, variant="auto"):
     else:
         body = partial(moe_mod.moe_psum, cfg=cfg)
         tok_spec = P(dp_axes, None)
-    from repro.compat import shard_map
-    mapped = shard_map(
+    mapped = jax.shard_map(
         lambda t, wr, wg, wu, wd: body(
             t, {"w_router": wr, "w_gate": wg, "w_up": wu, "w_down": wd}),
-        mesh,
-        (tok_spec, P(None, None)) + wspec,
-        (tok_spec, P()))
+        mesh=mesh, in_specs=(tok_spec, P(None, None)) + wspec,
+        out_specs=(tok_spec, P()), check_vma=False)
     out, aux = mapped(tokens, m["w_router"], m["w_gate"], m["w_up"],
                       m["w_down"])
     aux = jnp.mean(aux)

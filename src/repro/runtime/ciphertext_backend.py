@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import time
 import zlib
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -135,6 +135,25 @@ class CiphertextBackend:
         serving system cannot evict the evk mid-flight."""
         if self._key_cache is not None:
             self._key_cache.get_or_load(("engine",) + key, nbytes, pin=True)
+
+    @staticmethod
+    def pinned_key_bytes(traces: Iterable[FheTrace], params: CkksParams,
+                         pass_config=None) -> Tuple[int, int]:
+        """(evaluation keys, bytes) this backend pins to serve ``traces``
+        as the compiler emits them under ``pass_config``: the
+        relinearization key plus one Galois key per element the engine
+        switches to (`engine.galois_element`)."""
+        from repro.compiler import optimize_trace
+        from repro.compiler.engine import galois_element
+        from repro.core.trace import evk_bytes
+        elts = set()
+        for trace in traces:
+            if pass_config is not None:
+                trace, _ = optimize_trace(trace, params, pass_config)
+            elts.update(galois_element(op, params) for op in trace.ops)
+        elts.discard(None)
+        n_keys = 1 + len(elts)
+        return n_keys, n_keys * evk_bytes(params)
 
     def _sync_keys(self) -> None:
         """Register evaluation keys the engine already holds into the

@@ -48,6 +48,23 @@ from repro.runtime.metrics import MetricsRegistry
 from repro.runtime.queue import AdmissionQueue, Request, RequestStatus
 
 
+def workload_trace(fn: Callable, n_inputs: int,
+                   const_names: Sequence[str], start_level: int,
+                   pass_config: Optional[PassConfig]) -> FheTrace:
+    """Capture ``fn`` with its levels inferred from ``start_level``. A
+    trace deeper than the chain is admitted only when the compiler's
+    bootstrap-insertion pass will rewrite it at compile time (inputs
+    keep their level so the compiler knows the start); otherwise
+    LevelBudgetExhausted propagates."""
+    trace = trace_program(fn, n_inputs, const_names)
+    try:
+        infer_levels(trace, start_level=start_level)
+    except LevelBudgetExhausted:
+        if not (pass_config and pass_config.bootstrap):
+            raise
+    return trace
+
+
 @dataclasses.dataclass
 class Workload:
     """A registered FHE program: traced once, compiled per (params, mem)
@@ -414,18 +431,8 @@ class PipelinedExecutor:
     def register(self, name: str, fn: Callable, n_inputs: int,
                  const_names: Sequence[str] = (),
                  start_level: int = 10) -> Workload:
-        trace = trace_program(fn, n_inputs, const_names)
-        try:
-            infer_levels(trace, start_level=start_level)
-        except LevelBudgetExhausted:
-            # deeper than the chain: admissible only when the compiler's
-            # bootstrap-insertion pass will rewrite it at compile time
-            # (inputs keep their level so the compiler knows the start)
-            if not (self.pass_config and self.pass_config.bootstrap):
-                raise
-        w = Workload(name, trace)
-        self.workloads[name] = w
-        return w
+        return self.register_trace(name, workload_trace(
+            fn, n_inputs, const_names, start_level, self.pass_config))
 
     def register_trace(self, name: str, trace: FheTrace) -> Workload:
         w = Workload(name, trace)

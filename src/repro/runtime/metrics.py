@@ -181,6 +181,7 @@ class MetricsRegistry:
         # decrypt-side accuracy per workload (ciphertext backend):
         # max |decoded - reference| over every slot of every batch served
         self.decrypt_error: Dict[str, float] = {}
+        self.decrypt_batches: Dict[str, int] = {}     # batches checked
         self.elapsed_s = 0.0
         # observability attachment points (repro.obs). None = disabled;
         # every emission site in the stack guards on these being None,
@@ -195,6 +196,8 @@ class MetricsRegistry:
     def observe_decrypt_error(self, workload: str, err: float) -> None:
         prev = self.decrypt_error.get(workload, 0.0)
         self.decrypt_error[workload] = max(prev, float(err))
+        self.decrypt_batches[workload] = \
+            self.decrypt_batches.get(workload, 0) + 1
         self.incr("accuracy_batches_checked")
 
     def incr(self, name: str, by: int = 1) -> None:

@@ -38,8 +38,7 @@ def compressed_psum_body(g, err, *, axis: str):
     with one scalar pmax, payloads are requantized against it, and the int8
     payload is summed exactly in int32 — only ~1/4 of the bf16 bytes cross
     the DCN."""
-    from repro.compat import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     corrected = g[0].astype(F32) + err[0]
     _, scale = quantize_int8(corrected)
     gmax = jax.lax.pmax(scale, axis)
@@ -58,14 +57,13 @@ def compressed_pod_mean(per_pod_grads, err_tree, mesh: Mesh,
     internal data/model reduction); err leaves match. Returns
     (mean_grads without the pod dim, new_err_tree with it)."""
     def one(g, e):
-        from repro.compat import shard_map
-        fn = shard_map(
-            partial(compressed_psum_body, axis=axis),
-            mesh,
-            (P(axis, *([None] * (g.ndim - 1))),
-             P(axis, *([None] * (g.ndim - 1)))),
-            (P(*([None] * (g.ndim - 1))),
-             P(axis, *([None] * (g.ndim - 1)))))
+        fn = jax.shard_map(
+            partial(compressed_psum_body, axis=axis), mesh=mesh,
+            in_specs=(P(axis, *([None] * (g.ndim - 1))),
+                      P(axis, *([None] * (g.ndim - 1)))),
+            out_specs=(P(*([None] * (g.ndim - 1))),
+                       P(axis, *([None] * (g.ndim - 1)))),
+            check_vma=False)
         return fn(g, e)
 
     flat_g, td = jax.tree_util.tree_flatten(per_pod_grads)

@@ -13,7 +13,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
-from repro.compat import set_mesh as compat_set_mesh  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 
@@ -45,8 +44,8 @@ def scenario_bconv(variant: str):
 
 def scenario_pipeline():
     from repro.fhe_dist.pipeline_exec import run_load_save_pipeline
-    from repro.compat import make_mesh as _make_mesh
-    mesh = _make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(5, 16, 32)).astype(np.float32))
     fns_r1 = [lambda v, k=k: v * (k + 1) for k in range(8)]
@@ -90,7 +89,7 @@ def scenario_limb_sharded_hmul():
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh(1, 8)
     limb = NamedSharding(mesh, P(None, "model", None))
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         d1 = jax.device_put(ct1.data, limb)
         d2 = jax.device_put(ct2.data, limb)
         out = ops.hmul(ctx, Ciphertext(d1, L, scale),

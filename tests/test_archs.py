@@ -8,7 +8,6 @@ no allocation); see launch/dryrun.py and EXPERIMENTS.md §Dry-run.
 import numpy as np
 import pytest
 import jax
-from repro.compat import set_mesh as compat_set_mesh
 import jax.numpy as jnp
 
 from repro.configs import get_config, list_archs
@@ -42,7 +41,7 @@ def test_smoke_forward_and_loss(arch, mesh):
     rng = np.random.default_rng(0)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     batch = _batch(cfg, rng)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, mtp_logits, aux, _ = M.forward(params, cfg, batch, mesh)
         loss, metrics = M.loss_fn(params, cfg, batch, mesh)
     assert logits.shape == (2, 32, cfg.vocab)
@@ -59,7 +58,7 @@ def test_smoke_train_step(arch, mesh):
     params = M.init_params(cfg, jax.random.PRNGKey(1))
     opt = adamw_init(params)
     batch = _batch(cfg, rng)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = jax.jit(M.make_train_step(cfg, mesh))
         new_params, new_opt, metrics = step(params, opt, batch)
     assert np.isfinite(float(metrics["loss"]))
@@ -86,7 +85,7 @@ def test_smoke_decode_step(arch, mesh):
     if cfg.xattn_period:
         cache["images"] = jnp.asarray(
             rng.normal(size=(b, cfg.n_img_tokens, cfg.d_model)), jnp.bfloat16)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         serve = jax.jit(M.make_serve_step(cfg, mesh))
         tok = jnp.zeros((b,), jnp.int32)
         for pos in range(3):

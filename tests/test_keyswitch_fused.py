@@ -70,7 +70,7 @@ def test_fused_bit_equal_reference_across_levels(dnum):
     fks = FusedKeySwitch(ctx)
     for level in range(1, N_LEVELS + 1):
         d2 = _rand_d2(ctx, 2, level, seed=level)
-        km = fks.ksk_mont("relin", level, rk.data)
+        km = fks.ksk_mont("relin", rk.data)
         e0, e1 = fks.apply(d2, level, km, interpret=True)
         for i in range(d2.shape[0]):
             r0, r1 = hops.key_switch(ctx, d2[i], level, rk)
@@ -97,7 +97,7 @@ def test_fused_galois_key_bit_equal():
     fks = FusedKeySwitch(ctx)
     level = N_LEVELS - 1
     d2 = _rand_d2(ctx, 2, level, seed=5)
-    km = fks.ksk_mont(("gk", elt), level, gk.data)
+    km = fks.ksk_mont(("gk", elt), gk.data)
     e0, e1 = fks.apply(d2, level, km, interpret=True)
     for i in range(d2.shape[0]):
         r0, r1 = hops.key_switch(ctx, d2[i], level, gk)
@@ -113,7 +113,7 @@ def _measure_dispatches(dnum, level):
     ctx, _, _, rk = _setup(dnum)
     fks = FusedKeySwitch(ctx)
     d2 = _rand_d2(ctx, 1, level, seed=7)
-    km = fks.ksk_mont("relin", level, rk.data)
+    km = fks.ksk_mont("relin", rk.data)
     kcom.reset_dispatch_count()
     fks.apply(d2, level, km, interpret=True)
     fused = kcom.dispatch_count()
@@ -148,7 +148,7 @@ def test_dispatch_counts_golden():
 def test_dispatch_count_independent_of_batch():
     ctx, _, _, rk = _setup(2)
     fks = FusedKeySwitch(ctx)
-    km = fks.ksk_mont("relin", N_LEVELS, rk.data)
+    km = fks.ksk_mont("relin", rk.data)
     for batch in (1, 4):
         d2 = _rand_d2(ctx, batch, N_LEVELS, seed=batch)
         kcom.reset_dispatch_count()

@@ -7,7 +7,9 @@ from _hyp import given, settings, st  # noqa: E402  (skips per-test)
 
 from repro.core import modarith as ma
 from repro.core.params import (find_ntt_primes, generic_ntt_primes, is_prime,
+                               paper_params_bootstrap, paper_params_lola,
                                solinas_candidates)
+from repro.core.params import test_params as make_test_params
 
 # word32 moduli straight from the repo's own NTT-prime search — prime by
 # construction (the search Miller-Rabin-filters every candidate), so the
@@ -95,3 +97,11 @@ def test_prime_search_properties():
 def test_solinas_candidates_ntt_friendly():
     for p, b, s in solinas_candidates(31, 13):
         assert is_prime(p) and (p - 1) % (1 << 13) == 0
+
+
+@pytest.mark.parametrize("make", [paper_params_bootstrap, paper_params_lola,
+                                  make_test_params],
+                         ids=["bootstrap", "lola", "test"])
+def test_named_params_fit_word32(make):
+    """The u32 Pallas kernels need every modulus < 2^31 (word32 mode)."""
+    assert max(m.value for m in make().moduli) < 2 ** 31

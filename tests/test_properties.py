@@ -18,8 +18,8 @@ from repro.sharding.rules import default_rules, serving_rules, spec_for_shape
 # ---------------------------------------------------------------------------
 
 def _mesh(shape=(4, 4)):
-    from repro.compat import abstract_mesh
-    return abstract_mesh(shape, ("data", "model"))
+    import jax
+    return jax.sharding.AbstractMesh(tuple(shape), ("data", "model"))
 
 
 @settings(max_examples=60, deadline=None)

@@ -133,8 +133,8 @@ def test_compressed_psum_single_pod():
     the quantization residual."""
     from repro.train.compress import (compressed_pod_mean,
                                       init_error_feedback)
-    from repro.compat import make_mesh as _make_mesh
-    mesh = _make_mesh((1,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("pod",))
     g = {"w": jnp.asarray(np.linspace(-1, 1, 64,
                                       dtype=np.float32))[None]}  # (1, 64)
     err = init_error_feedback(g)
