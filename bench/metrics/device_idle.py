@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no op ran on the chip."""
+
+
+def read(run):
+    t = run.window.trace
+    if t is None or t.n_devices == 0 or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
