@@ -46,9 +46,9 @@ model bills for.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -63,6 +63,7 @@ from repro.core.encoder import CkksEncoder
 from repro.core.encryptor import CkksEncryptor
 from repro.core.params import CkksParams
 from repro.core.trace import FheOp, FheTrace, evk_bytes
+from repro.obs.hook import layer
 
 
 # ---------------------------------------------------------------------------
@@ -134,6 +135,30 @@ class CtBatch:
     @property
     def n_limbs(self) -> int:
         return self.level + 1
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: the name a jitted applier compiles and
+    profiles under (the op kind, not ``<lambda>``)."""
+    def applier(*a):
+        return fn(*a)
+    applier.__name__ = applier.__qualname__ = name
+    return applier
+
+
+def _observe_stage(span: layer, stage) -> None:
+    """A served stage's wall is its compute: its tracer span carries it
+    as ``compute_s`` (critical_path splits service by it), and armed
+    telemetry takes it at the stage's real end."""
+    sec = span.seconds
+    span.annotate(compute_s=sec)
+    tel = span.telemetry
+    if tel is not None:
+        t = span.end_at
+        tel.counter("fhe_partition_busy_seconds",
+                    partition=stage.partition).inc(t, sec)
+        tel.histogram("fhe_stage_wall_seconds",
+                      stage=stage.idx).observe(t, sec)
 
 
 def _default_cache_factory() -> Callable:
@@ -229,11 +254,13 @@ class CkksEngine:
     def decode_batch(self, cb: CtBatch) -> np.ndarray:
         """One batched decrypt dispatch, then per-element host decode."""
         from repro.core.encryptor import decrypt_data
-        m = np.asarray(decrypt_data(cb.data, self.sk.s_ntt,
-                                    self.ctx.q_all))        # (B, L, N)
-        return np.stack([self.encoder.decode(jnp.asarray(m[i]), cb.scale,
-                                             cb.level)
-                         for i in range(m.shape[0])])
+        with layer("decode", cts=cb.batch, limbs=cb.n_limbs):
+            with layer("decrypt"):
+                m = np.asarray(decrypt_data(cb.data, self.sk.s_ntt,
+                                            self.ctx.q_all))  # (B, L, N)
+            return np.stack([self.encoder.decode(jnp.asarray(m[i]),
+                                                 cb.scale, cb.level)
+                             for i in range(m.shape[0])])
 
     def encode_const(self, vec: np.ndarray, scale: float, level: int,
                      key: Optional[Tuple] = None) -> Plaintext:
@@ -262,12 +289,12 @@ class CkksEngine:
         if fn is not None:
             return fn
         if self.ntt_tables is None:
-            fn = jax.jit(build(self.ctx))
+            fn = jax.jit(_named(build(self.ctx), key[0]))
         else:
             from repro.kernels.limb_ntt import LimbNtt
             ctx = self.ctx
-            jitted = jax.jit(lambda tabs, *a: build(
-                ctx.with_transform(LimbNtt(tabs)))(*a))
+            jitted = jax.jit(_named(lambda tabs, *a: build(
+                ctx.with_transform(LimbNtt(tabs)))(*a), key[0]))
             fn = functools.partial(jitted, self.ntt_tables)
         self._opfns[key] = fn
         return fn
@@ -617,16 +644,21 @@ class CkksEngine:
         start = self._resolve_start(trace, start_level,
                                     self.params.n_levels)
         env: Dict[int, CtBatch] = {}
-        for i, idx in enumerate(trace.inputs):
-            env[idx] = self.encrypt_batch(np.asarray(inputs[i]), start)
-        jax.block_until_ready([c.data for c in env.values()])
+        with layer("encrypt", inputs=len(trace.inputs), level=start):
+            for i, idx in enumerate(trace.inputs):
+                env[idx] = self.encrypt_batch(np.asarray(inputs[i]), start)
+            jax.block_until_ready([c.data for c in env.values()])
         stage_seconds: List[float] = []
         for stage in schedule.stages:
-            t0 = time.perf_counter()
-            produced = self.run_ops(stage.ops, env, consts,
-                                    start_level=start,
-                                    const_scope=const_scope)
-            jax.block_until_ready([c.data for c in produced])
-            stage_seconds.append(time.perf_counter() - t0)
+            kinds = collections.Counter(op.kind for op in stage.ops
+                                        if op.kind not in ("input", "const"))
+            with layer("stage", stage=stage.idx, partition=stage.partition,
+                       **kinds) as span:
+                produced = self.run_ops(stage.ops, env, consts,
+                                        start_level=start,
+                                        const_scope=const_scope)
+                jax.block_until_ready([c.data for c in produced])
+            stage_seconds.append(span.seconds)
+            _observe_stage(span, stage)
         return ([self.decode_batch(env[o]) for o in trace.outputs],
                 stage_seconds)

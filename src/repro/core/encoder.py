@@ -18,6 +18,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core.context import CkksContext
+from repro.obs.hook import layer
 
 
 class CkksEncoder:
@@ -86,13 +87,16 @@ class CkksEncoder:
         """(level+1, N) NTT-domain plaintext -> complex slots (host)."""
         from repro.core import rns as rnsmod
         idx = self.ctx.q_idx(level)
-        coeff = np.asarray(self.ctx.intt(pt_ntt, idx))
+        with layer("intt", limbs=len(idx)):
+            coeff = np.asarray(self.ctx.intt(pt_ntt, idx))
         primes = [self.ctx.primes[i] for i in idx]
-        if len(primes) == 1:
-            q = primes[0]
-            c = coeff[0].astype(np.int64)
-            c = np.where(c > q // 2, c - q, c).astype(np.float64)
-        else:
-            lifted = rnsmod.crt_lift_centered(coeff, primes)
-            c = np.array([float(x) for x in lifted])
-        return self.embed_forward(c / scale)
+        with layer("lift", limbs=len(primes)):
+            if len(primes) == 1:
+                q = primes[0]
+                c = coeff[0].astype(np.int64)
+                c = np.where(c > q // 2, c - q, c).astype(np.float64)
+            else:
+                lifted = rnsmod.crt_lift_centered(coeff, primes)
+                c = np.array([float(x) for x in lifted])
+        with layer("embed"):
+            return self.embed_forward(c / scale)

@@ -11,15 +11,21 @@ the run's shared `MetricsRegistry`::
     ex.serve(...)
     write_trace(metrics.tracer.store, "trace.json")
 
-Absence of a tracer is the disabled state — every emission site in the
-runtime guards on ``metrics.tracer is None``, so a run without one is
-bit-for-bit identical to a build without this package (regression-
-tested against a metrics golden).
+Absence of a tracer is the disabled state — span emission in the
+runtime guards on ``metrics.tracer is None``, so a run without one
+serves bit-for-bit the metrics of a build without this package
+(regression-tested against a metrics golden).
 
 Time-series telemetry (repro.obs.telemetry) rides the same contract on
 ``metrics.telemetry``: bounded counter/gauge/histogram series on the
 caller's clock, exported as OpenMetrics text (repro.obs.openmetrics)
 or Perfetto counter tracks merged into the trace JSON.
+
+On the wall-clock ciphertext path, every layer boundary goes through
+one hook (repro.obs.hook) that feeds the tracer and telemetry when
+armed, and the JAX profiler and an always-on in-memory ring of layer
+records in every run: that part is not free, about 2 us a layer and
+0.6 us a generation-0 collection with nothing armed.
 """
 from repro.obs.span import Span, SpanStore
 from repro.obs.tracer import ExecObs, Tracer

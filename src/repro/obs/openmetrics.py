@@ -52,6 +52,10 @@ HELP: Dict[str, str] = {
         "per-partition busy fraction of the pipeline round",
     "fhe_stage_wall_seconds":
         "measured wall seconds per pipeline stage (ciphertext backend)",
+    "fhe_gc_collections":
+        "Python garbage collections during served batches, by generation",
+    "fhe_gc_seconds":
+        "seconds paused in those collections, by generation",
     "fhe_device_queue_depth": "queued requests per fleet device",
     "fhe_device_inflight_occupancy":
         "occupied fraction of a device's in-flight batch slots",

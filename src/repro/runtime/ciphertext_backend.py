@@ -24,7 +24,12 @@ Per batch:
   (`reference_eval`) on the same packed values — max |error| lands in
   ``MetricsRegistry.decrypt_error`` next to the latency percentiles;
 * per-stage wall times (completion barrier per stage) accumulate in
-  ``stage_stats`` — the measured side of benchmarks/fig18_calibration.
+  ``stage_stats`` — the measured side of benchmarks/fig18_calibration;
+* every layer boundary (``pack``, ``encrypt``, ``stage``, ``decode``
+  with ``decrypt``/``intt``/``lift``/``embed``, ``check``) goes
+  through the one hook of `repro.obs.hook`, which feeds the profiler,
+  the always-on ring, the tracer and telemetry from one measurement;
+  building a backend installs its garbage-collection listener.
 
 Workload inputs beyond the request payload (e.g. HELR's weight vector)
 and the named plaintext constants are synthesized deterministically per
@@ -43,6 +48,8 @@ from repro.compiler.interp import reference_eval
 from repro.core.params import CkksParams
 from repro.core.pipeline import PipelineSchedule
 from repro.core.trace import FheTrace
+from repro.obs import hook
+from repro.obs.hook import layer
 from repro.runtime.batcher import Batch
 from repro.runtime.keycache import KeyCache
 from repro.runtime.metrics import MetricsRegistry
@@ -113,6 +120,7 @@ class CiphertextBackend:
                                  const_cache=self._cached_const,
                                  on_key_load=self._on_key_load,
                                  use_kernels=use_kernels)
+        hook.install_gc_listener()
         # workload -> per-stage running means of measured seconds
         self.stage_stats: Dict[str, List[_StageStat]] = {}
         self.pad_batch_to: Optional[int] = None   # bucketing (executor sets)
@@ -233,24 +241,26 @@ class CiphertextBackend:
         self._sync_keys()
         n_micro = max(self.pad_batch_to or 0, batch.n_ciphertexts, 1)
 
-        t0 = time.perf_counter()
-        values = self._pack(batch, n_micro)
-        inputs = [values] + [self._aux_input(workload, i, n_micro)
-                             for i in range(1, len(trace.inputs))]
-        consts = self.workload_consts(workload, trace)
-        t_pack = time.perf_counter() - t0
-        outs, stage_s = self.engine.run_schedule(
-            schedule, inputs, consts, const_scope=(workload,))
-        dt = time.perf_counter() - t0
+        with hook.batch(obs, metrics.telemetry) as b:
+            with layer("pack", cts=n_micro, requests=len(batch.requests)):
+                values = self._pack(batch, n_micro)
+                inputs = [values] + [self._aux_input(workload, i, n_micro)
+                                     for i in range(1, len(trace.inputs))]
+                consts = self.workload_consts(workload, trace)
+            outs, stage_s = self.engine.run_schedule(
+                schedule, inputs, consts, const_scope=(workload,))
+            dt = time.perf_counter() - b.t_start
 
-        # decrypt-side accuracy vs the plaintext oracle on the very same
-        # packed values (reference_eval resolves derived cexprs too)
-        t_chk = time.perf_counter()
-        ref = reference_eval(trace, inputs, consts)
-        err = max(float(np.abs(np.asarray(d) - np.asarray(r)).max())
-                  for d, r in zip(outs, ref)) if outs else 0.0
-        metrics.observe_decrypt_error(workload, err)
-        t_chk = time.perf_counter() - t_chk
+            # decrypt-side accuracy vs the plaintext oracle on the very
+            # same packed values (reference_eval resolves derived cexprs
+            # too); outside the returned service seconds, so the next
+            # batch starts at its start on the executor's timeline: its
+            # span goes on a track of its own
+            with layer("check", track="host:check", cts=n_micro):
+                ref = reference_eval(trace, inputs, consts)
+                err = max(float(np.abs(np.asarray(d) - np.asarray(r)).max())
+                          for d, r in zip(outs, ref)) if outs else 0.0
+                metrics.observe_decrypt_error(workload, err)
 
         stats = self.stage_stats.setdefault(
             workload, [_StageStat() for _ in schedule.stages])
@@ -260,36 +270,6 @@ class CiphertextBackend:
         for st, sec in zip(schedule.stages, stage_s):
             stats[st.idx].add(sec)
             metrics.occupancy.add(st.partition, sec)
-
-        tel = metrics.telemetry
-        if tel is not None and obs is not None:
-            # wall-clock series (this backend's clock domain): measured
-            # per-stage seconds laid end to end after the pack window,
-            # mirroring the span decomposition below
-            at = obs.t0 + t_pack
-            for st, sec in zip(schedule.stages, stage_s):
-                at += sec
-                tel.counter("fhe_partition_busy_seconds",
-                            partition=st.partition).inc(at, sec)
-                tel.histogram("fhe_stage_wall_seconds",
-                              stage=st.idx).observe(at, sec)
-        if obs is not None and obs.tracer is not None:
-            # wall-clock decomposition: pack+encrypt, then the measured
-            # per-stage execution laid end to end
-            tr, t = obs.tracer, obs.t0
-            tr.span("encrypt_pack", t, t + t_pack, parent=obs.parent,
-                    track=obs.track, n_micro=n_micro)
-            at = t + t_pack
-            for st, sec in zip(schedule.stages, stage_s):
-                tr.span("stage", at, at + sec, parent=obs.parent,
-                        track=obs.track, stage=st.idx,
-                        partition=st.partition, compute_s=sec)
-                at += sec
-            # the oracle check runs after `dt` (outside the billed
-            # service window) — an instant with its wall cost as an
-            # attr keeps children inside the batch span's interval
-            tr.instant("decrypt_check", t + dt, parent=obs.parent,
-                       track=obs.track, wall_s=t_chk, max_err=err)
         batch.outputs = outs
         return dt
 

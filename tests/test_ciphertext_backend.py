@@ -184,3 +184,217 @@ def test_base_const_names_sees_through_cexprs():
                    cexpr=("mul", ("rot", ("ref", "w1"), 2), ("ref", "w2")))
     t.ops.append(derived)
     assert base_const_names(t) == sorted(LOLA_CONSTS)
+
+
+# ---------------------------------------------------------------------------
+# the layer hook (repro.obs.hook): one measurement per layer boundary feeds
+# the tracer, telemetry, the profiler and the always-on ring
+# ---------------------------------------------------------------------------
+
+def _lola_executor(backend):
+    ex = PipelinedExecutor(
+        PARAMS, MEM, backend=backend,
+        policy=BatchPolicy(slots_per_ct=PARAMS.slots, max_batch=2,
+                           max_wait_s=1e-3),
+        key_cache=KeyCache(64 * 2 ** 20), pass_config=CFG)
+    fn, n_in, consts = WORKLOADS["lola"]
+    ex.register("lola", fn, n_in, const_names=consts, start_level=START)
+    ex.warmup()
+    return ex
+
+
+def _lola_requests(ex, n):
+    rng = np.random.default_rng(5)
+    # a ciphertext each: two full batches of two for n = 4
+    return [Request(ex.queue.next_request_id(), f"t{i % 2}", "lola",
+                    arrival_s=i * 1e-4, slots_needed=PARAMS.slots,
+                    payload=rng.uniform(-0.8, 0.8, size=8))
+            for i in range(n)]
+
+
+def test_hook_spans_form_each_batch_tree(backend):
+    """pack -> encrypt -> stage x S -> decode(decrypt, intt/lift/embed
+    x B) -> check under every batch span, each where its work ran: the
+    stage spans last exactly the stage seconds run_schedule returns, the
+    stage series carry the same intervals, encrypt lies inside
+    run_schedule."""
+    import time
+    from repro.obs import Telemetry, Tracer
+    from repro.obs.hook import RING
+    ex = _lola_executor(backend)
+    tr = ex.metrics.tracer = Tracer()
+    tel = ex.metrics.telemetry = Telemetry(clock="wall")
+    eng = backend.engine
+    calls = []
+
+    def run_schedule(*a, **kw):
+        t0 = time.perf_counter()
+        outs, stage_s = type(eng).run_schedule(eng, *a, **kw)
+        calls.append((t0, time.perf_counter(), list(stage_s)))
+        return outs, stage_s
+    eng.run_schedule = run_schedule
+    try:
+        ex.serve(_lola_requests(ex, 4))
+    finally:
+        del eng.run_schedule
+    store = tr.store
+    batches = [s for s in store.roots() if s.name == "batch:lola"]
+    assert len(batches) == len(calls) >= 2
+    n_stages = len(ex.compile_cache.get_schedule(
+        ex.workloads["lola"].trace, PARAMS, MEM,
+        pass_config=CFG).stages)
+    for bspan, (_, _, stage_s) in zip(batches, calls):
+        kids = store.children(bspan.span_id)
+        names = [k.name for k in kids if k.name != "compile"]
+        assert names == (["pack", "encrypt"] + ["stage"] * n_stages
+                         + ["decode", "check"])
+        for a, b in zip(kids, kids[1:]):
+            assert a.end_s <= b.start_s
+        stages = [k for k in kids if k.name == "stage"]
+        assert [s.duration_s for s in stages] == pytest.approx(
+            stage_s, rel=1e-9, abs=1e-12)
+        assert [s.attrs["compute_s"] for s in stages] == stage_s
+        decode = next(k for k in kids if k.name == "decode")
+        n_ct = decode.attrs["cts"]
+        assert [k.name for k in store.children(decode.span_id)] == (
+            ["decrypt"] + ["intt", "lift", "embed"] * n_ct)
+        # every child but the check lies inside the batch span; the
+        # check runs after the service seconds the executor bills, on a
+        # track of its own
+        check = kids[-1]
+        assert check.start_s >= bspan.end_s
+        assert check.track == "host:check" != bspan.track
+        assert all(k.end_s <= bspan.end_s + 1e-12 for k in kids[:-1])
+    # spans of one track nest or follow each other: none cuts another
+    by_track = {}
+    for bspan in batches:
+        for s in store.subtree(bspan.span_id):
+            by_track.setdefault(s.track, []).append(s)
+    assert set(by_track) == {"device:0", "host:check"}
+    for spans in by_track.values():
+        spans.sort(key=lambda s: (s.start_s, -s.end_s))
+        for i, a in enumerate(spans):
+            for b in spans[i + 1:]:
+                if b.start_s >= a.end_s:
+                    break
+                assert b.end_s <= a.end_s + 1e-12, (a.name, b.name)
+    # the series hold the stage spans' intervals, stamped at their ends
+    stage_spans = store.by_name("stage")
+    hist_sum = sum(h.sum for h in tel.find("fhe_stage_wall_seconds"))
+    assert hist_sum == pytest.approx(sum(s.duration_s for s in stage_spans))
+    stamps = sorted(t for h in tel.find("fhe_partition_busy_seconds")
+                    for t, _ in h.points)
+    assert stamps == pytest.approx(sorted(s.end_s for s in stage_spans))
+    # on the ring: encrypt inside run_schedule's interval
+    recs = RING.records()
+    for t0, t1, _ in calls:
+        enc = [r for r in recs if r.name == "encrypt"
+               and t0 <= r.start <= t1]
+        assert len(enc) == 1 and enc[0].end <= t1
+
+
+def test_hook_profiler_events_match_ring_records(backend, compile_cache,
+                                                 tmp_path):
+    """Every ``fhe.*`` host event of a profile of one served batch is a
+    ring record: same names in the same order, durations within 5% or
+    50 us, and starts within 1 ms of where the ring's anchor puts them."""
+    import gc
+    import time
+    import jax
+    from jax.profiler import ProfileData
+    from repro.obs.hook import PREFIX, RING
+    sched = _schedule(compile_cache, "lola")
+    metrics = MetricsRegistry(MEM.n_partitions)
+    rng = np.random.default_rng(8)
+    backend.execute(sched, _batch("lola", rng), key_cache=None,
+                    metrics=metrics, workload="lola")      # warm
+    gc.collect()
+    gc.disable()                 # no collection lands between the two
+    try:
+        t0 = time.perf_counter()
+        jax.profiler.start_trace(str(tmp_path))
+        backend.execute(sched, _batch("lola", rng), key_cache=None,
+                        metrics=metrics, workload="lola")
+        jax.profiler.stop_trace()
+        t1 = time.perf_counter()
+    finally:
+        gc.enable()
+    recs = sorted((r for r in RING.records() if t0 <= r.start and r.end <= t1),
+                  key=lambda r: r.start)
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    pd = ProfileData.from_file(str(path))
+    env = next(p for p in pd.planes if p.name == "Task Environment")
+    origin_ns = dict(env.stats)["profile_start_time"]
+    events = sorted((ev for p in pd.planes if p.name.startswith("/host:")
+                     for ln in p.lines for ev in ln.events
+                     if ev.name.startswith(PREFIX)),
+                    key=lambda ev: ev.start_ns)
+    assert [ev.name for ev in events] == [PREFIX + r.name for r in recs]
+    assert {r.name for r in recs} >= {"pack", "encrypt", "stage", "decode",
+                                      "decrypt", "intt", "lift", "embed",
+                                      "check"}
+    for ev, r in zip(events, recs):
+        dur = r.seconds * 1e9
+        assert abs(ev.duration_ns - dur) <= max(0.05 * dur, 50e3), r.name
+        assert abs(origin_ns + ev.start_ns - RING.profiler_ns(r.start)) \
+            <= 1e6, r.name
+
+
+def test_hook_gc_listener_records_forced_collection(backend, compile_cache):
+    import gc
+    from repro.obs import ExecObs, Telemetry
+    from repro.obs.hook import GC, RING
+    assert GC in gc.callbacks            # installed with the backend
+    sched = _schedule(compile_cache, "lola")
+    pack = backend._pack
+
+    def pack_and_collect(*a, **kw):
+        gc.collect(2)
+        return pack(*a, **kw)
+    backend._pack = pack_and_collect
+    n2 = GC.count[2]
+    metrics = MetricsRegistry(MEM.n_partitions)
+    tel = metrics.telemetry = Telemetry(clock="wall")
+    try:
+        backend.execute(sched, _batch("lola", np.random.default_rng(9)),
+                        key_cache=None, metrics=metrics, workload="lola",
+                        obs=ExecObs(None, None, 0.0, "device:0"))
+    finally:
+        del backend._pack
+    assert GC.count[2] > n2 and GC.seconds[2] > 0
+    # armed telemetry takes the batch's collections by generation
+    assert tel.get("fhe_gc_collections", generation=2).value >= 1
+    assert tel.get("fhe_gc_seconds", generation=2).value > 0
+    recs = RING.records()
+    p = [r for r in recs if r.name == "pack"][-1]
+    gcs = [r for r in recs if r.name == "gc" and r.batch == p.batch
+           and r.attrs["generation"] == 2]
+    assert gcs and p.start <= gcs[0].start <= gcs[0].end <= p.end
+
+
+def test_hook_ring_bound_and_drop_count():
+    import time
+    from repro.obs.hook import Ring, layer
+    ring = Ring(size=4)
+    assert ring.dropped == 0 and ring.records() == []
+    for i in range(6):
+        ring.append(f"r{i}", float(i), i + 0.5, 1, {})
+    recs = ring.records()
+    assert [r.name for r in recs] == ["r2", "r3", "r4", "r5"]
+    assert [r.idx for r in recs] == [2, 3, 4, 5]
+    assert ring.dropped == 2
+    assert recs[0].seconds == 0.5
+    p, wall_ns = ring.anchor
+    assert ring.profiler_ns(p) == wall_ns
+    assert ring.profiler_ns(p + 1.0) - wall_ns == 10 ** 9
+    assert abs(ring.profiler_ns(time.perf_counter()) - time.time_ns()) < 1e8
+    ring.reanchor()                     # a batch re-takes the anchor
+    assert ring.anchor[0] > p
+    assert abs(ring.profiler_ns(time.perf_counter()) - time.time_ns()) < 1e8
+    # outside a batch a layer feeds the ring alone, under batch 0
+    with layer("lone", n=3) as span:
+        pass
+    from repro.obs.hook import RING
+    last = RING.records()[-1]
+    assert (last.name, last.batch, last.attrs) == ("lone", 0, {"n": 3})
+    assert last.seconds == span.seconds >= 0
