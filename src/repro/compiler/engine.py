@@ -248,19 +248,31 @@ class CkksEngine:
                        cts[0].scale)
 
     def decode(self, ct: Ciphertext) -> np.ndarray:
-        pt = self.encryptor.decrypt(ct, self.sk)
-        return self.encoder.decode(pt.data, ct.scale, ct.level)
+        return self.decode_batch(CtBatch(ct.data[None], ct.level,
+                                         ct.scale))[0]
 
     def decode_batch(self, cb: CtBatch) -> np.ndarray:
-        """One batched decrypt dispatch, then per-element host decode."""
-        from repro.core.encryptor import decrypt_data
+        """(B, slots) decodes: one program decrypts the batch and takes
+        every limb to coefficients, one lifts them to mixed-radix digits
+        (both on the device); the host finishes the lift and embeds."""
         with layer("decode", cts=cb.batch, limbs=cb.n_limbs):
             with layer("decrypt"):
-                m = np.asarray(decrypt_data(cb.data, self.sk.s_ntt,
-                                            self.ctx.q_all))  # (B, L, N)
-            return np.stack([self.encoder.decode(jnp.asarray(m[i]),
-                                                 cb.scale, cb.level)
-                             for i in range(m.shape[0])])
+                coeff = jax.block_until_ready(self._decrypt_coeffs(cb))
+            return self.encoder.decode_coeffs(coeff, cb.scale, cb.level)
+
+    def _decrypt_coeffs(self, cb: CtBatch) -> jnp.ndarray:
+        """c0 + c1·s, then the inverse NTT: (B, L, N) coefficients."""
+        from repro.core.encryptor import decrypt_data
+        lvl = cb.level
+
+        def build(ctx):
+            idx = ctx.q_idx(lvl)
+
+            def f(d, s):
+                return ctx.intt(decrypt_data(d, s, ctx.q_all), idx)
+            return jax.vmap(f, in_axes=(0, None))
+        return self._opfn(("decrypt", cb.batch, lvl), build)(
+            cb.data, self.sk.s_ntt)
 
     def encode_const(self, vec: np.ndarray, scale: float, level: int,
                      key: Optional[Tuple] = None) -> Plaintext:

@@ -78,6 +78,7 @@ class CkksContext:
                                 rns.BConvTables] = {}
         self._eval_perm_cache: Dict[int, jnp.ndarray] = {}
         self._limb_tables_cache: Dict[Tuple[int, ...], nttm.NttTables] = {}
+        self._lift_cache: Dict[int, rns.LiftTables] = {}
 
     # -- basis helpers ------------------------------------------------------
 
@@ -142,6 +143,19 @@ class CkksContext:
     @property
     def conj_element(self) -> int:
         return 2 * self.n - 1
+
+    def lift_tables(self, level: int) -> rns.LiftTables:
+        """Decode's mixed-radix lift constants at ``level``
+        (`rns.mixed_radix_centred`), on the device: the leading block of
+        the chain's tables and the digits of floor(Q_level / 2)."""
+        if level not in self._lift_cache:
+            self._lift_cache[level] = rns.LiftTables(
+                *map(jnp.asarray, self._chain_lift.prefix(level + 1)))
+        return self._lift_cache[level]
+
+    @functools.cached_property
+    def _chain_lift(self) -> rns.LiftTables:
+        return rns.lift_tables(self.q_primes)
 
     # -- misc ---------------------------------------------------------------
 

@@ -12,11 +12,13 @@ I/O, not the accelerated path the paper optimizes).
 """
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Sequence, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
+from repro.core import rns
 from repro.core.context import CkksContext
 from repro.obs.hook import layer
 
@@ -83,20 +85,31 @@ class CkksEncoder:
         return self.ctx.ntt(jnp.asarray(limbs), idx)
 
     def decode(self, pt_ntt: jnp.ndarray, scale: float,
-               level: int, max_error_check: bool = False) -> np.ndarray:
+               level: int) -> np.ndarray:
         """(level+1, N) NTT-domain plaintext -> complex slots (host)."""
-        from repro.core import rns as rnsmod
-        idx = self.ctx.q_idx(level)
-        with layer("intt", limbs=len(idx)):
-            coeff = np.asarray(self.ctx.intt(pt_ntt, idx))
-        primes = [self.ctx.primes[i] for i in idx]
-        with layer("lift", limbs=len(primes)):
-            if len(primes) == 1:
-                q = primes[0]
-                c = coeff[0].astype(np.int64)
-                c = np.where(c > q // 2, c - q, c).astype(np.float64)
-            else:
-                lifted = rnsmod.crt_lift_centered(coeff, primes)
-                c = np.array([float(x) for x in lifted])
+        coeff = self.ctx.intt(pt_ntt, self.ctx.q_idx(level))
+        return self.decode_coeffs(coeff, scale, level)
+
+    def decode_coeffs(self, coeff: jnp.ndarray, scale: float,
+                      level: int) -> np.ndarray:
+        """(..., level+1, N) coefficient-domain residues (on the device)
+        -> (..., slots) complex slots (host)."""
+        with layer("lift", limbs=level + 1) as span:
+            c, wide = self.lift(coeff, level)
+            span.annotate(wide=wide)
         with layer("embed"):
             return self.embed_forward(c / scale)
+
+    def lift(self, coeff: jnp.ndarray, level: int) -> Tuple[np.ndarray, int]:
+        """Centred CRT lift of (..., level+1, N) residues to float64
+        (..., N) on the host, bit-identical to ``float`` of
+        `rns.crt_lift_centered` below 2^53; also how many values reach
+        2^53 (where the float64 Horner may round otherwise)."""
+        primes = self.ctx.q_primes[: level + 1]
+        if len(primes) == 1:
+            q = primes[0]
+            c = np.asarray(coeff)[..., 0, :].astype(np.int64)
+            return np.where(c > q // 2, c - q, c).astype(np.float64), 0
+        digits, neg = jax.device_get(rns.mixed_radix_centred(
+            coeff, self.ctx.lift_tables(level)))
+        return rns.horner(digits, neg, primes)

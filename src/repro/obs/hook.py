@@ -203,7 +203,9 @@ class layer:
         return self._b.at(self.end)
 
     def annotate(self, **attrs) -> None:
-        """Add ``attrs`` to the layer's tracer span, if it has one."""
+        """Add ``attrs`` to the layer's ring record and to its tracer
+        span, if it has one."""
+        self.attrs.update(attrs)
         if self._sid is not None:
             self._b.obs.tracer.store.get(self._sid).attrs.update(attrs)
 

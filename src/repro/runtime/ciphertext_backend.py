@@ -26,7 +26,7 @@ Per batch:
 * per-stage wall times (completion barrier per stage) accumulate in
   ``stage_stats`` — the measured side of benchmarks/fig18_calibration;
 * every layer boundary (``pack``, ``encrypt``, ``stage``, ``decode``
-  with ``decrypt``/``intt``/``lift``/``embed``, ``check``) goes
+  with ``decrypt``/``lift``/``embed``, ``check``) goes
   through the one hook of `repro.obs.hook`, which feeds the profiler,
   the always-on ring, the tracer and telemetry from one measurement;
   building a backend installs its garbage-collection listener.

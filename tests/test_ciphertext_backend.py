@@ -187,6 +187,64 @@ def test_base_const_names_sees_through_cexprs():
 
 
 # ---------------------------------------------------------------------------
+# batched decode: the device lift against the per-ciphertext host path
+# ---------------------------------------------------------------------------
+
+def _decode_one_by_one(eng, cb):
+    """decode_batch as it was: one batched decrypt, then per ciphertext
+    the inverse NTT, the big-integer CRT lift and the embedding."""
+    import jax.numpy as jnp
+    from repro.core import rns
+    from repro.core.encryptor import decrypt_data
+    idx = eng.ctx.q_idx(cb.level)
+    primes = [eng.ctx.primes[i] for i in idx]
+    m = np.asarray(decrypt_data(cb.data, eng.sk.s_ntt, eng.ctx.q_all))
+    out = []
+    for i in range(cb.batch):
+        coeff = np.asarray(eng.ctx.intt(jnp.asarray(m[i]), idx))
+        if len(primes) == 1:
+            c = coeff[0].astype(np.int64)
+            c = np.where(c > primes[0] // 2, c - primes[0], c)
+            c = c.astype(np.float64)
+        else:
+            c = np.array([float(x)
+                          for x in rns.crt_lift_centered(coeff, primes)])
+        out.append(eng.encoder.embed_forward(c / cb.scale))
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("level", [0, 2, START])
+@pytest.mark.parametrize("n_ct", [1, 3])
+def test_decode_batch_matches_per_ciphertext_path(backend, level, n_ct):
+    """decode_batch (one decrypt + iNTT program, one lift program, the
+    Horner and one embedding on the host) returns the very floats of the
+    per-ciphertext host path, and one decode leaves one ``decode`` ring
+    record that encloses ``decrypt``, ``lift`` (with ``wide``, 0 at
+    these values) and ``embed``."""
+    import time
+    from repro.obs.hook import RING
+    eng = backend.engine
+    rng = np.random.default_rng(10 * level + n_ct)
+    v = (rng.uniform(-1, 1, (n_ct, PARAMS.slots))
+         + 1j * rng.uniform(-1, 1, (n_ct, PARAMS.slots)))
+    cb = eng.encrypt_batch(v, level)
+    want = _decode_one_by_one(eng, cb)
+    t0 = time.perf_counter()
+    got = eng.decode_batch(cb)
+    assert got.shape == want.shape == (n_ct, PARAMS.slots)
+    assert np.array_equal(np.ascontiguousarray(got).view(np.uint64),
+                          want.view(np.uint64))
+    assert np.abs(got - v).max() < 1e-3
+    recs = [r for r in RING.records() if r.start >= t0 and r.name != "gc"]
+    assert [r.name for r in recs] == ["decrypt", "lift", "embed", "decode"]
+    decode = recs[-1]
+    assert decode.attrs == {"cts": n_ct, "limbs": level + 1}
+    assert all(decode.start <= r.start <= r.end <= decode.end
+               for r in recs[:-1])
+    assert recs[1].attrs == {"limbs": level + 1, "wide": 0}
+
+
+# ---------------------------------------------------------------------------
 # the layer hook (repro.obs.hook): one measurement per layer boundary feeds
 # the tracer, telemetry, the profiler and the always-on ring
 # ---------------------------------------------------------------------------
@@ -213,8 +271,8 @@ def _lola_requests(ex, n):
 
 
 def test_hook_spans_form_each_batch_tree(backend):
-    """pack -> encrypt -> stage x S -> decode(decrypt, intt/lift/embed
-    x B) -> check under every batch span, each where its work ran: the
+    """pack -> encrypt -> stage x S -> decode(decrypt, lift, embed) ->
+    check under every batch span, each where its work ran: the
     stage spans last exactly the stage seconds run_schedule returns, the
     stage series carry the same intervals, encrypt lies inside
     run_schedule."""
@@ -255,9 +313,8 @@ def test_hook_spans_form_each_batch_tree(backend):
             stage_s, rel=1e-9, abs=1e-12)
         assert [s.attrs["compute_s"] for s in stages] == stage_s
         decode = next(k for k in kids if k.name == "decode")
-        n_ct = decode.attrs["cts"]
-        assert [k.name for k in store.children(decode.span_id)] == (
-            ["decrypt"] + ["intt", "lift", "embed"] * n_ct)
+        assert [k.name for k in store.children(decode.span_id)] == [
+            "decrypt", "lift", "embed"]
         # every child but the check lies inside the batch span; the
         # check runs after the service seconds the executor bills, on a
         # track of its own
@@ -331,7 +388,7 @@ def test_hook_profiler_events_match_ring_records(backend, compile_cache,
                     key=lambda ev: ev.start_ns)
     assert [ev.name for ev in events] == [PREFIX + r.name for r in recs]
     assert {r.name for r in recs} >= {"pack", "encrypt", "stage", "decode",
-                                      "decrypt", "intt", "lift", "embed",
+                                      "decrypt", "lift", "embed",
                                       "check"}
     for ev, r in zip(events, recs):
         dur = r.seconds * 1e9

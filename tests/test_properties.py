@@ -8,7 +8,9 @@ from _hyp import given, settings, st  # noqa: E402  (skips per-test)
 from repro.compiler import PassConfig, optimize_trace, reference_eval
 from repro.compiler.passes import PASS_ORDER
 from repro.core import rns
-from repro.core.params import find_ntt_primes, test_params as make_test_params
+from repro.core.params import (find_ntt_primes, paper_params_bootstrap,
+                               paper_params_lola,
+                               test_params as make_test_params)
 from repro.core.trace import FheOp, FheTrace, infer_levels
 from repro.sharding.rules import default_rules, serving_rules, spec_for_shape
 
@@ -76,6 +78,52 @@ def test_crt_lift_roundtrip_property(seed):
     limbs = np.stack([(xs % p).astype(np.uint64) for p in PRIMES])
     lifted = rns.crt_lift_centered(limbs, PRIMES)
     assert all(int(a) == int(b) for a, b in zip(lifted, xs))
+
+
+LIFT_CHAINS = {name: [m.value for m in make().q_moduli]
+               for name, make in (("boot", paper_params_bootstrap),
+                                  ("lola", paper_params_lola))}
+
+
+@pytest.mark.parametrize("chain,n_limbs", [
+    (name, n) for name, primes in LIFT_CHAINS.items()
+    for n in range(2, len(primes) + 1)])
+def test_mixed_radix_lift_matches_crt_property(chain, n_limbs):
+    """decode's lift (device digits + host Horner) against the exact
+    big-integer CRT: the same float, bit for bit, below 2^53 (random
+    values and the edges, floor(Q/2) and the most negative centred value
+    among them); beyond, within one rounding a limb, counted by `wide`."""
+    import random
+    full = LIFT_CHAINS[chain]
+    primes = full[:n_limbs]
+    big_q = int(np.prod([int(p) for p in primes], dtype=object))
+    half = big_q // 2
+    rng = np.random.default_rng(n_limbs)
+    edges = [0, 1, -1, 2**53 - 1, -(2**53 - 1), half, -half]
+    small = [int(x) for x in rng.integers(-2**52 + 1, 2**52, size=48)]
+    pick = random.Random(n_limbs)
+    big = [pick.choice((1, -1)) * pick.randrange(2**53, half + 1)
+           for _ in range(16)]
+    xs = [x for x in edges + small + big if abs(x) <= half]
+    limbs = np.array([[x % p for x in xs] for p in primes], dtype=np.uint64)
+    tabs = rns.lift_tables(full).prefix(n_limbs)
+    assert all(np.array_equal(a, b)
+               for a, b in zip(tabs, rns.lift_tables(primes)))
+    digits, neg = rns.mixed_radix_centred(jnp.asarray(limbs), tabs)
+    digits, neg = np.asarray(digits), np.asarray(neg)
+    assert neg.tolist() == [x < 0 for x in xs]
+    assert digits.T.tolist() == [rns.mixed_radix(abs(x), primes)
+                                 for x in xs]
+    got, wide = rns.horner(digits, neg, primes)
+    exact = [int(c) for c in rns.crt_lift_centered(limbs, primes)]
+    assert exact == xs
+    narrow = np.array([abs(x) < 2**53 for x in xs])
+    want = np.array([float(x) for x in exact])
+    assert np.array_equal(got[narrow].view(np.uint64),
+                          want[narrow].view(np.uint64))
+    rel = np.abs(got[~narrow] - want[~narrow]) / np.abs(want[~narrow])
+    assert (rel <= n_limbs * 2.0**-52).all()
+    assert wide == int((~narrow).sum()) > 0
 
 
 @settings(max_examples=30, deadline=None)

@@ -119,3 +119,16 @@ def test_bconv_compiles_for_v5e(one_chip, lazy):
     def fn(v, w, p, pi):
         return bconv_pallas(v, w, p, pi, lazy=lazy, interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, (v, w, p, p), one_chip)
+
+
+def test_decode_lift_compiles_for_v5e(one_chip, paper_ctx):
+    """decode's mixed-radix lift at the served shape: a loop of Shoup
+    multiplications, so it compiles in seconds (an unrolled form of the
+    recurrence took minutes)."""
+    import time
+    from repro.core import rns
+    tabs = paper_ctx.lift_tables(LEVEL - 1)
+    a = jax.ShapeDtypeStruct((BATCH, LEVEL, paper_ctx.n), jnp.uint64)
+    t0 = time.perf_counter()
+    _compiled_text(rns.mixed_radix_centred, (a, tabs), one_chip)
+    assert time.perf_counter() - t0 < 60
