@@ -38,17 +38,24 @@ operands of an add have structurally identical scales; across a level
 gap the deeper operand is brought down *exactly* with a compensating
 unit pmul (`linalg.adjust_to` semantics, batched here).
 
-`bootstrap` ops execute as an exact refresh (decrypt -> re-encode at
-the target level -> re-encrypt): the semantic contract of
-bootstrapping without the minutes-long EvalMod chain; the full
-approximate pipeline lives in core/bootstrap.py and is what the cost
-model bills for.
+`bootstrap` ops run real CKKS bootstrapping whenever the parameter set
+declares bootstrap settings (`CkksParams.has_bootstrap`): ModRaise,
+the factored CoeffToSlot, EvalMod on the real and imaginary parts
+batched together, and the factored SlotToCoeff (core/bootstrap.py's
+`BootstrapPlan`), all on the batch through the appliers above and the
+fused keyswitch, landing at exactly the level the compiler assumed
+(``PassConfig.bootstrap_to``) with the scale of a fresh encryption.
+Its Galois keys and DFT diagonals are made on first use and pinned.
+A parameter set without bootstrap settings takes the test-scale refresh
+instead (`_test_scale_refresh`: decrypt and re-encrypt under the
+server's key), which only toy ring sizes reach.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import functools
+import math
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -61,7 +68,7 @@ from repro.core.ciphertext import Ciphertext, KeySwitchKey, Plaintext
 from repro.core.context import CkksContext
 from repro.core.encoder import CkksEncoder
 from repro.core.encryptor import CkksEncryptor
-from repro.core.params import CkksParams
+from repro.core.params import BootstrapLevelError, CkksParams
 from repro.core.trace import FheOp, FheTrace, evk_bytes
 from repro.obs.hook import layer
 
@@ -161,6 +168,52 @@ def _observe_stage(span: layer, stage) -> None:
                       stage=stage.idx).observe(t, sec)
 
 
+TEST_SCALE_MAX_LOG_N = 12    # largest ring the test-scale refresh serves
+
+
+def _ready(cb: CtBatch) -> CtBatch:
+    jax.block_until_ready(cb.data)
+    return cb
+
+
+def _backsolve(s_end: float, primes: Sequence[int]) -> float:
+    """The scale s_0 whose squarings, each rescaled by the next of
+    ``primes`` (s_{k+1} = s_k^2 / primes[k]), end at ``s_end``."""
+    log = math.log2(s_end)
+    for q in reversed(primes):
+        log = (log + math.log2(q)) / 2
+    return 2.0 ** log
+
+
+def mod_raise_fn(ctx: CkksContext, level: int) -> Callable:
+    """ModRaise of one ciphertext's data (2, L, N): q0's limb times the
+    gain (a residue mod q0), to coefficients, centred, and into every
+    prime of ``level`` (NTT domain)."""
+    from repro.core import modarith as ma
+    q0 = ctx.primes[0]
+    idx = ctx.q_idx(level)
+    q = ctx.q_all[: level + 1][:, None]
+    u = jnp.uint64
+
+    def f(d, gain):
+        c = ctx.intt(ma.mulmod(d[:, :1], gain, u(q0)), [0])
+        r = c % q                                           # (2, L, N)
+        return ctx.ntt(jnp.where(c > u(q0 // 2),
+                                 ma.submod(r, u(q0) % q, q), r), idx)
+    return f
+
+
+def _observe_bootstrap(span: layer) -> None:
+    """Armed telemetry takes each bootstrap's keyswitches and wall at
+    its real end."""
+    tel = span.telemetry
+    if tel is not None:
+        t = span.end_at
+        tel.counter("fhe_bootstrap_keyswitches").inc(
+            t, span.attrs["keyswitches"])
+        tel.histogram("fhe_bootstrap_wall_seconds").observe(t, span.seconds)
+
+
 def _default_cache_factory() -> Callable:
     memo: Dict = {}
 
@@ -211,6 +264,8 @@ class CkksEngine:
         self.use_kernels = use_kernels
         self.use_kernel_modmul = use_kernel_modmul or use_kernels
         self._fks = None
+        self.n_keyswitches = 0           # relinearizations and rotations
+        self._bts_pts: Dict[Tuple, Plaintext] = {}   # pinned bootstrap pts
         if on_key_load is not None:
             on_key_load(("relin",), evk_bytes(params))
 
@@ -224,14 +279,38 @@ class CkksEngine:
         bits above observed error on the registered workloads)."""
         return 512.0 * self.params.n / 2.0 ** self.params.log_scale
 
+    @property
+    def bootstrap_tolerance(self) -> float:
+        """Decrypt-error bound of one bootstrap. EvalMod computes at a
+        scale near Δ on y = x / (K+1), x = t / q0, and its result is read
+        back as the message: times (K+1) q0 / (gain Δ). So the bound is
+        this parameter set's CKKS tolerance times that factor."""
+        p = self.params
+        return self.tolerance * (p.bts_k_range + 1) * p.bts_msg_ratio
+
     # -- keys ----------------------------------------------------------------
 
-    def _gk(self, elt: int) -> KeySwitchKey:
+    def _gk(self, elt: int) -> Optional[KeySwitchKey]:
+        """The Galois key for ``elt``, made on first use. On the kernel
+        route only the fused keyswitch's Montgomery copy is kept (None
+        here), so each key occupies HBM once."""
         if elt not in self._gks:
-            self._gks.update(self.encryptor.galois_keygen(self.sk, [elt]))
+            gk = self.encryptor.galois_keygen(self.sk, [elt])[elt]
+            if self.use_kernels:
+                self.fused_ks.ksk_mont(("gk", elt), gk.data)
+                gk = None
+            self._gks[elt] = gk
             if self.on_key_load is not None:
                 self.on_key_load(("gk", elt), evk_bytes(self.params))
         return self._gks[elt]
+
+    def pinned(self) -> List[Tuple[Tuple, int]]:
+        """(key, bytes) of everything the engine holds for good: the
+        relinearization key, each Galois key, each bootstrap plaintext."""
+        nb = evk_bytes(self.params)
+        return ([(("relin",), nb)] + [(("gk", e), nb) for e in self._gks]
+                + [(("bts",) + k, (pt.level + 1) * self.params.n * 8)
+                   for k, pt in self._bts_pts.items()])
 
     # -- encrypt / decode ----------------------------------------------------
 
@@ -319,25 +398,55 @@ class CkksEngine:
 
     def _adjust_to(self, cb: CtBatch, level: int, scale: float) -> CtBatch:
         """Batched linalg.adjust_to: exact (level, scale) landing via a
-        unit pmul at a compensating plaintext scale."""
+        unit multiply at a compensating plaintext scale, then a rescale."""
         assert cb.level > level
         cb = self._mod_switch(cb, level + 1)
         q_drop = self.ctx.primes[level + 1]
-        pt_scale = scale * q_drop / cb.scale
-        pt = self.encode_const(np.ones(self.params.slots), pt_scale,
-                               level + 1, key=("unit",))
-        key = ("adjust", cb.batch, cb.level, float(cb.scale), float(scale))
+        out = self._rescale(self._mul_const(cb, 1.0, scale * q_drop
+                                            / cb.scale))
+        return CtBatch(out.data, level, scale)   # exact by construction
+
+    def _residues(self, k: int, level: int) -> jnp.ndarray:
+        """The integer ``k`` mod each prime of ``level``'s basis."""
+        return jnp.asarray(np.array([k % q for q in
+                                     self.ctx.q_primes[: level + 1]],
+                                    dtype=np.uint64))
+
+    def _mul_const(self, cb: CtBatch, value: float,
+                   pt_scale: float) -> CtBatch:
+        """Multiply by the real constant ``value`` encoded at
+        ``pt_scale``, without a rescale. A constant slot vector encodes
+        to the constant polynomial round(value * pt_scale), which is that
+        integer at every NTT point: one multiply per limb, and the same
+        product a pmul by its encoded plaintext gives."""
+        k = int(round(value * pt_scale))
+        key = ("mul_const", cb.batch, cb.level)
 
         def build(ctx):
-            lvl, s = cb.level, cb.scale
+            q = ctx.q_all[: cb.n_limbs][:, None]
 
-            def f(d, ptd):
-                out = hops.pmul(ctx, Ciphertext(d, lvl, s),
-                                Plaintext(ptd, lvl, pt_scale))
-                return out.data
+            def f(d, r):
+                from repro.core import modarith as ma
+                return ma.mulmod(d, r[:, None], q)
             return jax.vmap(f, in_axes=(0, None))
-        data = self._opfn(key, build)(cb.data, pt.data)
-        return CtBatch(data, level, scale)       # exact by construction
+        data = self._opfn(key, build)(cb.data, self._residues(k, cb.level))
+        return CtBatch(data, cb.level, cb.scale * pt_scale)
+
+    def _add_const(self, cb: CtBatch, value: float) -> CtBatch:
+        """Add the real constant ``value`` at the batch's scale (a padd
+        of the constant slot vector, as one add per limb)."""
+        k = int(round(value * cb.scale))
+        key = ("add_const", cb.batch, cb.level)
+
+        def build(ctx):
+            q = ctx.q_all[: cb.n_limbs][:, None]
+
+            def f(d, r):
+                from repro.core import modarith as ma
+                return jnp.stack([ma.addmod(d[0], r[:, None], q), d[1]])
+            return jax.vmap(f, in_axes=(0, None))
+        data = self._opfn(key, build)(cb.data, self._residues(k, cb.level))
+        return CtBatch(data, cb.level, cb.scale)
 
     def _aligned(self, c0: CtBatch, c1: CtBatch) -> Tuple[CtBatch, CtBatch]:
         """Bring an hadd/hsub pair to one (level, scale); exact across a
@@ -428,6 +537,7 @@ class CkksEngine:
         return out if lazy else self._rescale(out)
 
     def _hmul(self, c0: CtBatch, c1: CtBatch, lazy: bool) -> CtBatch:
+        self.n_keyswitches += 1
         if self.use_kernels:
             return self._hmul_fused(c0, c1, lazy)
         lvl = min(c0.level, c1.level)
@@ -519,7 +629,7 @@ class CkksEngine:
         """Galois automorphism with the keyswitch on the fused kernels:
         jitted NTT-domain permutation -> 4-kernel keyswitch of the
         rotated `a` batch -> jitted combine. Bit-identical to `_galois`."""
-        gk = self._gk(elt)
+        self._gk(elt)
         lvl = cb.level
         perm = self.ctx.eval_perm(elt)
         key = ("galois_rot", cb.batch, lvl)
@@ -530,7 +640,7 @@ class CkksEngine:
                 return d[0][:, perm_], d[1][:, perm_]
             return jax.vmap(f, in_axes=(0, None))
         rot_b, rot_a = self._opfn(key, build_rot)(cb.data, perm)
-        km = self.fused_ks.ksk_mont(("gk", elt), gk.data)
+        km = self.fused_ks.ksk_mont(("gk", elt), None)
         e0, e1 = self.fused_ks.apply(rot_a, lvl, km)
         ckey = ("galois_combine", cb.batch, lvl)
 
@@ -544,20 +654,225 @@ class CkksEngine:
         return CtBatch(data, lvl, cb.scale)
 
     def _galois(self, cb: CtBatch, elt: int) -> CtBatch:
+        self.n_keyswitches += 1
         if self.use_kernels:
             return self._galois_fused(cb, elt)
         gk = self._gk(elt)
-        key = ("galois", cb.batch, cb.level, elt)
+        key = ("galois", cb.batch, cb.level)
 
         def build(ctx):
-            lvl, s = cb.level, cb.scale
+            lvl = cb.level
+            q = ctx.q_all[: lvl + 1][:, None]
 
-            def f(d, gkd):
-                return hops._apply_galois(ctx, Ciphertext(d, lvl, s),
-                                          elt, KeySwitchKey(gkd)).data
-            return jax.vmap(f, in_axes=(0, None))
-        return CtBatch(self._opfn(key, build)(cb.data, gk.data),
+            # hops._apply_galois with the permutation an argument: one
+            # program for every element
+            def f(d, gkd, perm):
+                from repro.core import modarith as ma
+                e0, e1 = hops.key_switch(ctx, d[1][:, perm], lvl,
+                                         KeySwitchKey(gkd))
+                return jnp.stack([ma.addmod(d[0][:, perm], e0, q), e1])
+            return jax.vmap(f, in_axes=(0, None, None))
+        return CtBatch(self._opfn(key, build)(cb.data, gk.data,
+                                              self.ctx.eval_perm(elt)),
                        cb.level, cb.scale)
+
+    # -- bootstrapping -------------------------------------------------------
+
+    def bootstrap(self, cb: CtBatch, target: int) -> CtBatch:
+        """Refresh ``cb`` to exactly level ``target`` at the scale of a
+        fresh encryption: real CKKS bootstrapping where the parameter set
+        has bootstrap settings, else the test-scale refresh. Nothing
+        leaves the device in between; each phase ends in a barrier so its
+        span holds its own device time."""
+        p = self.params
+        if not p.has_bootstrap:
+            return self._test_scale_refresh(cb, target)
+        from repro.core.bootstrap import bootstrap_plan
+        raise_level = p.bts_raise_level(target)
+        plan = bootstrap_plan(p)
+        # the integer gain that brings this input to the message ratio
+        q0 = self.ctx.primes[0]
+        gain = max(1, round(q0 / (cb.scale * p.bts_msg_ratio)))
+        ratio = q0 / (gain * cb.scale)
+        ks0 = self.n_keyswitches
+        with layer("bootstrap", cts=cb.batch, level_in=cb.level,
+                   level_out=target) as span:
+            with layer("mod_raise"):
+                x = _ready(self._mod_raise(cb, raise_level, gain))
+            with layer("cts"):
+                y = _ready(self._coeff_to_slot(x, plan))
+            with layer("evalmod"):
+                g = _ready(self._eval_mod(y, plan, ratio))
+            with layer("stc"):
+                out = _ready(self._slot_to_coeff(g, plan, ratio))
+            span.annotate(keyswitches=self.n_keyswitches - ks0)
+        _observe_bootstrap(span)
+        assert out.level == target
+        return out
+
+    def _test_scale_refresh(self, cb: CtBatch, target: int) -> CtBatch:
+        """The refresh of a parameter set without bootstrap settings:
+        decrypt under the server's secret key and encrypt again at
+        ``target``. Exact, and no bootstrap at all: it serves the CPU
+        tests' toy rings, and a ring above logN=12 (every benchmark
+        configuration) refuses it."""
+        if self.params.log_n > TEST_SCALE_MAX_LOG_N:
+            raise BootstrapLevelError(target, self.params)
+        return self.encrypt_batch(self.decode_batch(cb), target)
+
+    def _bts_pt(self, key: Tuple, vec, scale: float,
+                level: int) -> Plaintext:
+        """A bootstrap plaintext, encoded on first use and held (pinned)
+        for the engine's life."""
+        pt = self._bts_pts.get(key)
+        if pt is None:
+            pt = self._bts_pts[key] = Plaintext(
+                self.encoder.encode(vec, scale, level), level, scale)
+            if self.on_key_load is not None:
+                self.on_key_load(("bts",) + key,
+                                 (level + 1) * self.params.n * 8)
+        return pt
+
+    def _mod_raise(self, cb: CtBatch, level: int, gain: int) -> CtBatch:
+        """Keep q0's limb, multiply it by ``gain`` (exact), and lift its
+        centred residues into every prime of ``level``: the ciphertext
+        then holds t = gain·m + q0·I with |I| <= K. The scale declared,
+        2·q0·(K+1), makes the real and imaginary parts of CoeffToSlot's
+        output y = t / (q0 (K+1)), in [-1, 1]."""
+        q0 = self.ctx.primes[0]
+        key = ("mod_raise", cb.batch, level)
+        data = self._opfn(key, lambda ctx: jax.vmap(
+            mod_raise_fn(ctx, level), in_axes=(0, None)))(
+                cb.data, jnp.uint64(gain % q0))
+        return CtBatch(data, level,
+                       2.0 * q0 * (self.params.bts_k_range + 1))
+
+    def _dft_level(self, x: CtBatch, lv, name: Tuple,
+                   shrink: float) -> CtBatch:
+        """One level of a factored DFT (`core.bootstrap.DftLevel`) by
+        baby-step giant-step, its diagonals encoded at ``shrink`` times
+        the prime the level drops (so the scale falls by that factor);
+        one rescale at the end."""
+        pt_scale = shrink * self.ctx.q_primes[x.level]
+        rots = {0: x}
+        out = None
+        for i, terms in lv.terms.items():
+            inner = None
+            for j, diag in terms:
+                if j not in rots:
+                    rots[j] = self._galois(
+                        x, self.ctx.rotation_element(lv.gap * j))
+                pt = self._bts_pt(name + (i, j, x.level), diag, pt_scale,
+                                  x.level)
+                t = self._pmul(rots[j], pt, lazy=True)
+                inner = t if inner is None else self._addsub("hadd", inner,
+                                                             t)
+            if i:
+                inner = self._galois(
+                    inner, self.ctx.rotation_element(lv.gap * lv.baby * i))
+            out = inner if out is None else self._addsub("hadd", out, inner)
+        return self._rescale(out)
+
+    def _coeff_to_slot(self, x: CtBatch, plan) -> CtBatch:
+        """CoeffToSlot, then the real and imaginary parts (w + conj w and
+        -i (w - conj w)) stacked as one batch of 2B, at the scale EvalMod's
+        ladder starts from."""
+        lvl = x.level - len(plan.cts)
+        s_y = _backsolve(2.0 ** self.params.log_scale, self.ctx.q_primes[
+            lvl - plan.ladder_depth + 1: lvl + 1][::-1])
+        shrink = (s_y / x.scale) ** (1.0 / len(plan.cts))
+        for k, lv in enumerate(plan.cts):
+            x = self._dft_level(x, lv, ("cts", k), shrink)
+        xc = self._galois(x, 2 * self.params.n - 1)
+        re = self._addsub("hadd", x, xc)
+        im = self._pmul(self._addsub("hsub", x, xc),
+                        self._bts_pt(("-i", lvl), -1j, 1.0, lvl), lazy=True)
+        return CtBatch(jnp.concatenate([re.data, im.data]), lvl, x.scale)
+
+    def _same_level(self, a: CtBatch, b: CtBatch) -> Tuple[CtBatch, CtBatch]:
+        """Bring the higher operand exactly to the other's (level, scale),
+        so a product's scale depends on its level alone."""
+        if a.level > b.level:
+            a = self._adjust_to(a, b.level, b.scale)
+        elif b.level > a.level:
+            b = self._adjust_to(b, a.level, a.scale)
+        return a, b
+
+    def _double_minus(self, t: CtBatch, sub) -> CtBatch:
+        """2 t - sub (``sub``: a batch, or a constant)."""
+        t = self._addsub("hadd", t, t)
+        if isinstance(sub, CtBatch):
+            return self._addsub("hsub", t, sub)
+        return self._add_const(t, -sub)
+
+    def _eval_mod(self, y: CtBatch, plan, ratio: float) -> CtBatch:
+        """EvalMod on the stacked parts y = x / (K+1): the cosine's
+        Chebyshev series on the power-of-two ladder (T_2d = 2 T_d^2 - 1,
+        T_{lo+hi} = 2 T_lo T_hi - T_{lo-hi}; every value of one level at
+        one scale), then r double angles, so the slots hold
+        sin(2 pi x) ~ 2 pi x', x' = gain m / q0, the message over this
+        input's message ratio. It ends at Δ·ratio / bts_msg_ratio, the
+        scale that `_slot_to_coeff` reads back at a fixed one."""
+        p = self.params
+        deg, r = p.bts_cheb_degree, p.bts_double_angle
+        q = self.ctx.q_primes
+        low = y.level - plan.ladder_depth     # every term is brought here
+        s_end = 2.0 ** p.log_scale * ratio / p.bts_msg_ratio
+        s_sum = _backsolve(s_end, q[low - r: low][::-1])
+        operands = {1}
+        for i in range(2, deg + 1):
+            lo = 1 << (i.bit_length() - 1)
+            operands.update((lo // 2,) if lo == i else (lo, i - lo,
+                                                        2 * lo - i))
+        ts = {1: y}
+        acc = None
+
+        def fold(i, t):               # acc += c_i T_i at level `low`, lazy
+            nonlocal acc
+            t = self._mod_switch(t, low)
+            term = self._mul_const(t, plan.cheb[i], s_sum * q[low] / t.scale)
+            acc = term if acc is None else self._addsub("hadd", acc, term)
+
+        for i in range(2, deg + 1):
+            lo = 1 << (i.bit_length() - 1)
+            if lo == i:
+                h = ts[lo // 2]
+                t = self._double_minus(self._hmul(h, h, lazy=False), 1.0)
+            else:
+                a, b = self._same_level(ts[lo], ts[i - lo])
+                t = self._double_minus(self._hmul(a, b, lazy=False),
+                                       ts[2 * lo - i])
+            if i in operands:
+                ts[i] = t
+            else:
+                fold(i, t)
+        for i in sorted(ts):
+            fold(i, ts.pop(i))
+        c = self._add_const(self._rescale(acc), plan.cheb[0])
+        for _ in range(r):
+            c = self._double_minus(self._hmul(c, c, lazy=False), 1.0)
+        return c
+
+    def _slot_to_coeff(self, g: CtBatch, plan, ratio: float) -> CtBatch:
+        """Undo the split (re + i·im), read the slots as the message's
+        coefficients, and SlotToCoeff them down to the target level at
+        the scale of a fresh encryption."""
+        p = self.params
+        half = g.batch // 2
+        lvl = g.level
+        re = CtBatch(g.data[:half], lvl, g.scale)
+        im = self._pmul(CtBatch(g.data[half:], lvl, g.scale),
+                        self._bts_pt(("+i", lvl), 1j, 1.0, lvl), lazy=True)
+        z = self._addsub("hadd", re, im)
+        delta = 2.0 ** p.log_scale
+        # z holds sin(2 pi x) ~ 2 pi x' at scale Δ·ratio / bts_msg_ratio;
+        # read at 2 pi Δ / bts_msg_ratio (fixed, so the diagonals are
+        # too) it holds x'·ratio = m / s_in: the message's coefficients
+        x = CtBatch(z.data, lvl, z.scale * 2 * math.pi / ratio)
+        shrink = (p.bts_msg_ratio / (2 * math.pi)) ** (1.0 / len(plan.stc))
+        for k, lv in enumerate(plan.stc):
+            x = self._dft_level(x, lv, ("stc", k), shrink)
+        return CtBatch(x.data, x.level, delta)
 
     # -- op-by-op evaluation -------------------------------------------------
 
@@ -597,7 +912,7 @@ class CkksEngine:
                 out = self._rescale(a[0])
             elif op.kind == "bootstrap":
                 target = op.level if op.level is not None else start_level
-                out = self.encrypt_batch(self.decode_batch(a[0]), target)
+                out = self.bootstrap(a[0], target)
             else:
                 raise ValueError(op.kind)
             env[op.idx] = out

@@ -20,6 +20,9 @@ accelerator:
   `bootstrap` op on the deepest operand of the failing op, repeat. The
   as-late-as-possible cut point maximizes levels consumed per refresh,
   which minimizes the number of bootstraps for any straight-line chain.
+  Every refresh lands at ``PassConfig.bootstrap_to`` (the start level by
+  default); a parameter set whose bootstrap settings cannot reach it
+  fails here, at compile time, with `BootstrapLevelError`.
 
 Every pass is functional (fresh trace out) and funnels through
 `ir.finish`, so outputs are canonical and never carry dead code. The
@@ -325,6 +328,10 @@ class BootstrapInsertion(Pass):
         for _ in range(len(trace.ops) + 8):
             try:
                 infer_levels(t, start, config.bootstrap_to)
+                if params.has_bootstrap and any(op.kind == "bootstrap"
+                                                for op in t.ops):
+                    # the real refresh must reach the level assumed here
+                    params.bts_raise_level(boot_to)
                 return t
             except LevelBudgetExhausted as e:
                 fail = t.ops[e.op_index]

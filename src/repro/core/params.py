@@ -178,6 +178,15 @@ class CkksParams:
     prefer_solinas: bool = True
     error_std: float = 3.2
     hamming_weight_sk: int = 64            # secret key density
+    # bootstrapping (core/bootstrap.py). With ``bts_dft_levels`` 0 the set
+    # has no bootstrap settings, and a `bootstrap` op takes the engine's
+    # test-scale refresh instead.
+    bts_dft_levels: int = 0       # levels of CoeffToSlot, and of SlotToCoeff
+    bts_cheb_degree: int = 31     # EvalMod: Chebyshev degree of the cosine
+    bts_double_angle: int = 3     # EvalMod: double-angle steps after it
+    bts_k_range: int = 16         # ModRaise's overflow bound: |I| <= K
+    bts_msg_ratio: float = 1 / 16  # q0 over the message's scale as
+    #                               EvalMod sees it (set by an integer gain)
 
     @property
     def n(self) -> int:
@@ -222,12 +231,87 @@ class CkksParams:
     def p_moduli(self) -> Tuple[Modulus, ...]:
         return self.moduli[self.n_q_moduli:]
 
+    # -- bootstrapping ---------------------------------------------------------
+
+    @property
+    def has_bootstrap(self) -> bool:
+        return self.bts_dft_levels > 0
+
+    @property
+    def bts_evalmod_levels(self) -> int:
+        """EvalMod's levels: the Chebyshev ladder, its linear
+        combination, then the double-angle steps."""
+        return (chebyshev_ladder_depth(self.bts_cheb_degree) + 1
+                + self.bts_double_angle)
+
+    @property
+    def bts_levels(self) -> int:
+        """Levels one bootstrap consumes between ModRaise and its output."""
+        return 2 * self.bts_dft_levels + self.bts_evalmod_levels
+
+    @property
+    def bts_output_level(self) -> int:
+        """The highest level a bootstrap lands at: ModRaise to the top
+        of the chain, less the levels the pipeline consumes."""
+        return self.n_levels - self.bts_levels
+
+    @property
+    def bts_coeff_bound(self) -> float:
+        """The largest |coefficient| of a message (in units of its
+        slot values) the settings admit: the one that reaches a quarter
+        of q0 at the message ratio, where EvalMod's sine turns over and
+        no longer recovers it. Slot values of spread data have
+        coefficients about sqrt(N) times smaller than themselves."""
+        return self.bts_msg_ratio / 4.0
+
+    def bts_raise_level(self, target: int) -> int:
+        """The level ModRaise lifts to for a bootstrap landing at
+        ``target``; BootstrapLevelError where the settings cannot land
+        there (a refresh can never land above `bts_output_level`)."""
+        if not self.has_bootstrap or not 0 <= target <= self.bts_output_level:
+            raise BootstrapLevelError(target, self)
+        return target + self.bts_levels
+
     def digit_indices(self, level: int) -> List[List[int]]:
         """Key-switch digit grouping of q-indices at `level` (L'=level+1 primes)."""
         n_active = level + 1
         return [list(range(d * self.alpha, min((d + 1) * self.alpha, n_active)))
                 for d in range(self.dnum)
                 if d * self.alpha < n_active]
+
+
+class BootstrapLevelError(ValueError):
+    """A bootstrap asked to land at a level the parameter set's
+    bootstrap settings cannot reach. ``target`` is the level asked for,
+    ``reachable`` the highest a refresh lands at (None: the set has no
+    bootstrap settings), ``consumed`` the levels the pipeline uses."""
+
+    def __init__(self, target: int, params: "CkksParams"):
+        self.target = target
+        self.reachable = (params.bts_output_level if params.has_bootstrap
+                          else None)
+        self.consumed = params.bts_levels if params.has_bootstrap else None
+        if self.reachable is None:
+            why = "the parameter set has no bootstrap settings"
+        else:
+            why = (f"ModRaise to level {params.n_levels} less the "
+                   f"{self.consumed} levels of CoeffToSlot, EvalMod and "
+                   f"SlotToCoeff reaches level {self.reachable} at most")
+        super().__init__(f"a bootstrap cannot land at level {target}: "
+                         f"{why}; lower the start level or "
+                         f"PassConfig.bootstrap_to")
+
+
+def chebyshev_ladder_depth(degree: int) -> int:
+    """Multiplicative depth of T_1..T_degree built by the power-of-two
+    ladder (T_2d = 2 T_d^2 - 1, T_{lo+hi} = 2 T_lo T_hi - T_{lo-hi};
+    `linalg.poly_eval_chebyshev` and the engine's EvalMod)."""
+    depth = {1: 0}
+    for i in range(2, degree + 1):
+        lo = 1 << (i.bit_length() - 1)
+        depth[i] = (depth[lo // 2] + 1 if lo == i
+                    else max(depth[lo], depth[i - lo]) + 1)
+    return max(depth.values())
 
 
 # Presets -------------------------------------------------------------------
@@ -247,10 +331,15 @@ def paper_params_bootstrap() -> CkksParams:
     same logQ budget is met with more, narrower limbs (DESIGN.md §2).
     logPQ here ≈ (24·28 + 31) + 7·30 ≈ 913 bits vs paper's 1556 with wide
     limbs — the *structure* (L, dnum, N) is what the pipeline exercises.
+
+    Bootstrapping: CoeffToSlot and SlotToCoeff in 4 levels each (the 15
+    butterfly layers of the special FFT as radix 2^4, 2^4, 2^4, 2^3),
+    EvalMod a degree-31 cosine and 3 double angles (9 levels), so a
+    refresh lands at level 23 - 17 = 6.
     """
     return CkksParams(log_n=16, log_scale=28, n_levels=23, dnum=4,
                       first_mod_bits=31, scale_mod_bits=28,
-                      special_mod_bits=31)
+                      special_mod_bits=31, bts_dft_levels=4)
 
 
 def paper_params_lola() -> CkksParams:

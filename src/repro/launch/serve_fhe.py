@@ -296,6 +296,13 @@ def run(argv: Optional[List[str]] = None) -> ServeResult:
                     help="emit one JSON line per request lifecycle "
                          "event (accepted/routed/preempted/completed/"
                          "dropped...) to stdout as it happens")
+    ap.add_argument("--start-level", type=int, default=None,
+                    help="level requests are encrypted at (default: 20 at "
+                         "the paper's parameters, 7 with --smoke); at the "
+                         "paper's parameters a start level of 6 or less "
+                         "lets a deep program (poly) take real "
+                         "bootstrapping, which refreshes to the start "
+                         "level")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -323,6 +330,8 @@ def run(argv: Optional[List[str]] = None) -> ServeResult:
         params = paper_params_bootstrap()
         start_level = 20
         mem = MemoryModel(n_partitions=16, partition_bytes=96 * 2 ** 20)
+    if args.start_level is not None:
+        start_level = args.start_level
 
     # shared preset registry (repro.pim.arch): the pim backend recovers
     # its arch from the mem via resolve_backend, so pricing and DES use
@@ -345,8 +354,9 @@ def run(argv: Optional[List[str]] = None) -> ServeResult:
     encrypt = not args.no_encrypt and args.backend != "ciphertext"
     cache_mb = CONST_CACHE_MB if args.cache_mb is None else args.cache_mb
     if args.backend == "ciphertext" and cache_mb > 0:
-        # every evaluation key is pinned: size the cache for them (or
-        # refuse an explicit size that cannot hold them) before warmup
+        # every evaluation key and bootstrap plaintext is pinned: size
+        # the cache for them (or refuse an explicit size that cannot
+        # hold them) before warmup
         from repro.runtime.ciphertext_backend import CiphertextBackend
         pass_config = PassConfig() if args.opt else None
         n_keys, key_bytes = CiphertextBackend.pinned_key_bytes(
@@ -357,8 +367,9 @@ def run(argv: Optional[List[str]] = None) -> ServeResult:
             cache_mb = need_mb + CONST_CACHE_MB
         elif cache_mb < need_mb:
             ap.error(f"--cache-mb {cache_mb} cannot hold the {n_keys} "
-                     f"evaluation keys the registered workloads pin "
-                     f"({need_mb} MiB at logN={params.log_n}); pass "
+                     f"evaluation keys (and any bootstrap plaintexts) the "
+                     f"registered workloads pin ({need_mb} MiB at "
+                     f"logN={params.log_n}); pass "
                      f"--cache-mb {need_mb + CONST_CACHE_MB} or more")
     if args.fleet > 0:
         ex = build_fleet_scheduler(

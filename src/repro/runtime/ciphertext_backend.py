@@ -139,8 +139,8 @@ class CiphertextBackend:
         return value
 
     def _on_key_load(self, key: Tuple, nbytes: int) -> None:
-        """Evaluation keys (relin / Galois) are pinned residents: a
-        serving system cannot evict the evk mid-flight."""
+        """Evaluation keys (relin / Galois) and bootstrap plaintexts are
+        pinned residents: a serving system cannot evict them mid-flight."""
         if self._key_cache is not None:
             self._key_cache.get_or_load(("engine",) + key, nbytes, pin=True)
 
@@ -150,32 +150,43 @@ class CiphertextBackend:
         """(evaluation keys, bytes) this backend pins to serve ``traces``
         as the compiler emits them under ``pass_config``: the
         relinearization key plus one Galois key per element the engine
-        switches to (`engine.galois_element`)."""
+        switches to (`engine.galois_element`); where a trace bootstraps
+        on a parameter set with bootstrap settings, also the bootstrap's
+        Galois and conjugation keys and, in the bytes, its DFT
+        plaintexts at each level it refreshes to."""
         from repro.compiler import optimize_trace
         from repro.compiler.engine import galois_element
+        from repro.core.bootstrap import bootstrap_plan
         from repro.core.trace import evk_bytes
-        elts = set()
+        elts, targets = set(), set()
         for trace in traces:
             if pass_config is not None:
                 trace, _ = optimize_trace(trace, params, pass_config)
             elts.update(galois_element(op, params) for op in trace.ops)
+            targets.update(op.level for op in trace.ops
+                           if op.kind == "bootstrap")
         elts.discard(None)
+        pt_bytes = 0
+        if targets and params.has_bootstrap:
+            plan = bootstrap_plan(params)
+            elts.update(plan.galois_elements())
+            pt_bytes = sum(plan.plaintext_limbs(params.bts_raise_level(t))
+                           for t in targets) * params.n * 8
         n_keys = 1 + len(elts)
-        return n_keys, n_keys * evk_bytes(params)
+        return n_keys, n_keys * evk_bytes(params) + pt_bytes
 
     def _sync_keys(self) -> None:
-        """Register evaluation keys the engine already holds into the
-        wired KeyCache (pinned). Keys may have been generated before
-        this cache was attached — residency accounting must not depend
-        on generation timing. Only MISSING keys are loaded: pinned
-        entries never leave, and re-touching them every batch would
-        inflate the hit-rate metrics the serving sweeps report."""
+        """Register what the engine already holds for good (evaluation
+        keys, bootstrap plaintexts) into the wired KeyCache (pinned).
+        Keys may have been generated before this cache was attached —
+        residency accounting must not depend on generation timing. Only
+        MISSING entries are loaded: pinned entries never leave, and
+        re-touching them every batch would inflate the hit-rate metrics
+        the serving sweeps report."""
         if self._key_cache is None:
             return
-        from repro.core.trace import evk_bytes
-        nb = evk_bytes(self.engine.params)
-        for key in [("engine", "relin")] + [("engine", "gk", elt)
-                                            for elt in self.engine._gks]:
+        for key, nb in self.engine.pinned():
+            key = ("engine",) + key
             if key not in self._key_cache:
                 self._key_cache.get_or_load(key, nb, pin=True)
 

@@ -23,6 +23,20 @@ def make_helr_iter(rot_steps: Sequence[int] = (1, 2, 4, 8)):
     return helr_iter
 
 
+def make_helr_train(iters: int = 2, rot_steps: Sequence[int] = (1, 2, 4, 8)):
+    """HELR training: ``iters`` steps of the `make_helr_iter` body on one
+    input ``x``, carrying the server-side weights ``w`` from step to
+    step. Past the start level's budget the compiler's bootstrap
+    insertion refreshes it between (or inside) the steps."""
+    step = make_helr_iter(rot_steps)
+
+    def helr_train(x, w, consts=None):
+        for _ in range(iters):
+            w = step(x, w, consts)
+        return w
+    return helr_train
+
+
 HELR_CONSTS: Tuple[str, ...] = ("c1", "c3")
 
 

@@ -66,17 +66,34 @@ def _compiled_text(fn, args, sharding) -> str:
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
-def test_fused_keyswitch_compiles_for_v5e(one_chip, paper_ctx, paper_tables):
+def _fused_keyswitch_text(one_chip, ctx, tables, batch, level) -> str:
     from repro.kernels.keyswitch import FusedKeySwitch
-    ctx = paper_ctx
-    fks = FusedKeySwitch(ctx, paper_tables)
-    run = fks._build(BATCH, LEVEL, itp=False)
-    tabs = fks._tables(LEVEL)
-    d2 = jax.ShapeDtypeStruct((BATCH, LEVEL + 1, ctx.n), jnp.uint64)
+    fks = FusedKeySwitch(ctx, tables)
+    run = fks._build(batch, level, itp=False)
+    tabs = fks._tables(level)
+    d2 = jax.ShapeDtypeStruct((batch, level + 1, ctx.n), jnp.uint64)
     ksk_m = jax.ShapeDtypeStruct((ctx.params.dnum, 2, len(ctx.primes),
                                   ctx.n // 128, 128), jnp.uint32)
-    text = _compiled_text(run, (d2, ksk_m, paper_tables, tabs.arrays),
-                          one_chip)
+    return _compiled_text(run, (d2, ksk_m, tables, tabs.arrays), one_chip)
+
+
+def test_fused_keyswitch_compiles_for_v5e(one_chip, paper_ctx, paper_tables):
+    from repro.kernels.keyswitch import FusedKeySwitch
+    text = _fused_keyswitch_text(one_chip, paper_ctx, paper_tables, BATCH,
+                                 LEVEL)
+    assert text.count("tpu_custom_call") >= FusedKeySwitch.DISPATCHES_PER_APPLY
+
+
+# the top of the chain (a bootstrap's CoeffToSlot after ModRaise) and
+# EvalMod's real and imaginary parts stacked as one batch
+@pytest.mark.parametrize("batch,level", [(BATCH, 23), (2 * BATCH, 19)],
+                         ids=["modraise", "evalmod"])
+def test_fused_keyswitch_compiles_for_v5e_bootstrap(one_chip, paper_ctx,
+                                                    paper_tables, batch,
+                                                    level):
+    from repro.kernels.keyswitch import FusedKeySwitch
+    text = _fused_keyswitch_text(one_chip, paper_ctx, paper_tables, batch,
+                                 level)
     assert text.count("tpu_custom_call") >= FusedKeySwitch.DISPATCHES_PER_APPLY
 
 
@@ -132,3 +149,25 @@ def test_decode_lift_compiles_for_v5e(one_chip, paper_ctx):
     t0 = time.perf_counter()
     _compiled_text(rns.mixed_radix_centred, (a, tabs), one_chip)
     assert time.perf_counter() - t0 < 60
+
+
+def test_mod_raise_compiles_for_v5e(one_chip, paper_ctx, paper_tables,
+                                   monkeypatch):
+    """The bootstrap's ModRaise on the kernel route (limb iNTT of q0's
+    limb, the centred lift, the limb NTT into all 24 primes)."""
+    from repro.compiler.engine import mod_raise_fn
+    from repro.kernels import limb_ntt
+    from repro.kernels.limb_ntt import LimbNtt
+    # the limb NTT picks interpret mode off the CPU backend this process
+    # runs on; the compile here is for the described chip
+    monkeypatch.setattr(limb_ntt, "default_interpret", lambda: False)
+    params = paper_params_bootstrap()
+
+    def fn(tabs, d, gain):
+        ctx = paper_ctx.with_transform(LimbNtt(tabs))
+        return jax.vmap(mod_raise_fn(ctx, params.n_levels),
+                        in_axes=(0, None))(d, gain)
+    d = jax.ShapeDtypeStruct((BATCH, 2, 1, params.n), jnp.uint64)
+    gain = jax.ShapeDtypeStruct((), jnp.uint64)
+    assert "tpu_custom_call" in _compiled_text(fn, (paper_tables, d, gain),
+                                               one_chip)
